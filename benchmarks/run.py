@@ -1104,6 +1104,8 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

@@ -9,11 +9,13 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax.numpy as jnp
 
 from repro.core import Buffer, Context, DispatchQueue, ErrBox, Program, memcheck
+from repro.core.compile_cache import use_compile_cache
 from repro.prof import Prof, queue_chart
 
+use_compile_cache()
 err = ErrBox()
 
-# Context over the best available device(s)  (ccl_context_new_gpu)
+# Context over the devices of the backend JAX started on  (ccl_context_new_gpu)
 ctx = Context.new_accel(err=err)
 err.check()
 dev = ctx.device(0)

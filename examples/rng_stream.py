@@ -18,6 +18,7 @@ import sys
 import threading
 
 from repro.core import Context, DispatchQueue, ErrBox, memcheck, swap
+from repro.core.compile_cache import use_compile_cache
 from repro.kernels.xorshift_prng import ops as prng
 from repro.prof import Prof, export_table, queue_chart
 
@@ -29,6 +30,7 @@ def main() -> int:
     numrn = int(sys.argv[1]) if len(sys.argv) >= 2 else NUMRN_DEFAULT
     numiter = int(sys.argv[2]) if len(sys.argv) >= 3 else NUMITER_DEFAULT
 
+    use_compile_cache()
     err = ErrBox()
     ctx = Context.new_accel(err=err)
     err.check()

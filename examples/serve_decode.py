@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
 from repro.core import Context, DispatchQueue
+from repro.core.compile_cache import use_compile_cache
 from repro.models.model import init_params
 from repro.prof import Prof, queue_chart
 from repro.serve.step import (DECODE_EVENT, PREFILL_EVENT,
@@ -40,6 +41,7 @@ def main() -> int:
                          " (mixed-depth traffic: see serve_engine.py)")
     args = ap.parse_args()
 
+    use_compile_cache()
     import dataclasses
     cfg = dataclasses.replace(get_smoke_config(args.arch),
                               attn_impl=args.attn_impl)

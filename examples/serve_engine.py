@@ -12,6 +12,19 @@ For the lockstep-batch reference driver see ``serve_decode.py``.
 
 Run:  PYTHONPATH=src python examples/serve_engine.py --requests 8
 
+``--full`` serves the published-width config (``get_config``; SmolLM-360M
+unless ``--arch`` says otherwise) at a real size — 8 slots, a 2048-position
+budget, prompts of 128–1024 tokens with 32 new tokens each — which is for
+the chip, not the CPU:
+
+    PYTHONPATH=src python examples/serve_engine.py --full --paged \
+        --attn-impl pallas
+
+Prefill and decode both run ``--attn-impl``.  Lane errors are not retried
+(``max_submission_retries=0``): a compile or device error stops the run
+with its own message, and the example exits non-zero if any request
+ends ``FAILED`` without a ``--deadline-ticks`` having asked for that.
+
 ``--metrics`` prints the end-of-run metrics registry (latency
 percentiles in ticks, counters, gauges) plus the per-request span
 Gantt; ``--trace out.json`` writes the merged device+request timeline
@@ -20,36 +33,52 @@ in Chrome ``trace_event`` format — load it at ``ui.perfetto.dev``.
 
 import argparse
 import dataclasses
+import sys
 
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
+from repro.core.compile_cache import use_compile_cache
 from repro.core.errors import err_string
 from repro.models.model import init_params
 from repro.prof import (Prof, compile_summary, export_perfetto,
                         queue_chart, render_request_gantt)
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import Request, ServeEngine, Status
+
+# --full defaults: SmolLM-360M at a size one TPU v5e serves for real
+FULL = dict(arch="smollm-360m", slots=8, budget=2048, page_size=128,
+            prompt_lens=(128, 1024), new_tokens=(32, 32))
+SMOKE = dict(arch="mixtral-8x7b", slots=4, budget=96, page_size=8,
+             prompt_lens=(8, 24), new_tokens=(6, 20))
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="mixtral-8x7b",
-                    help="architecture id (smoke config is used)")
-    ap.add_argument("--slots", type=int, default=4,
-                    help="decode batch width (standing cache slots)")
-    ap.add_argument("--budget", type=int, default=96,
-                    help="decode position budget per slot")
+    ap.add_argument("--full", action="store_true",
+                    help="published-width config (get_config) at a real "
+                         "size; the default is the tiny smoke config")
+    ap.add_argument("--arch", default=None,
+                    help="architecture id (default: mixtral-8x7b smoke, "
+                         "smollm-360m with --full)")
+    ap.add_argument("--slots", type=int, default=None,
+                    help="decode batch width (standing cache slots; "
+                         "default 4, 8 with --full)")
+    ap.add_argument("--budget", type=int, default=None,
+                    help="decode position budget per slot (default 96, "
+                         "2048 with --full)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--mean-gap", type=float, default=2.0,
                     help="Poisson mean inter-arrival gap in ticks")
     ap.add_argument("--attn-impl", default="xla", choices=["xla", "pallas"],
-                    help="decode path: jnp reference or fused Pallas kernel")
+                    help="attention for prefill and decode: jnp reference "
+                         "or the Pallas flash / fused decode kernels")
     ap.add_argument("--paged", action="store_true",
                     help="serve from the paged KV pool instead of dense "
                          "per-slot rings")
-    ap.add_argument("--page-size", type=int, default=8,
-                    help="positions per KV page (paged mode)")
+    ap.add_argument("--page-size", type=int, default=None,
+                    help="positions per KV page (paged mode; default 8, "
+                         "128 with --full)")
     ap.add_argument("--pool-pages", type=int, default=None,
                     help="arena pages per cache kind (default: dense-"
                          "equivalent full provision; smaller values "
@@ -92,27 +121,34 @@ def main() -> int:
                          "per-request span Gantt")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    size = FULL if args.full else SMOKE
+    for name in ("arch", "slots", "budget", "page_size"):
+        if getattr(args, name) is None:
+            setattr(args, name, size[name])
 
-    cfg = dataclasses.replace(get_smoke_config(args.arch),
-                              attn_impl=args.attn_impl)
+    use_compile_cache()
+    get = get_config if args.full else get_smoke_config
+    cfg = dataclasses.replace(get(args.arch), attn_impl=args.attn_impl)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
 
     rng = np.random.default_rng(args.seed)
     arrivals = np.cumsum(rng.poisson(args.mean_gap, size=args.requests))
     system = [int(t) for t in rng.integers(0, cfg.vocab,
                                            args.system_prompt)]
+    lo, hi = size["prompt_lens"]
+    new_lo, new_hi = size["new_tokens"]
     reqs = [Request(i,
                     system +
                     [int(t) for t in rng.integers(0, cfg.vocab,
-                                                  rng.integers(8, 25))],
-                    int(rng.integers(6, 21)), arrival=int(a),
+                                                  rng.integers(lo, hi + 1))],
+                    int(rng.integers(new_lo, new_hi + 1)), arrival=int(a),
                     deadline_ticks=args.deadline_ticks)
             for i, a in enumerate(arrivals)]
 
     eng = ServeEngine(cfg, params, n_slots=args.slots, budget=args.budget,
-                      prefill_impl="xla", paged=args.paged,
-                      page_size=args.page_size, pool_pages=args.pool_pages,
-                      buckets=args.buckets)
+                      paged=args.paged, page_size=args.page_size,
+                      pool_pages=args.pool_pages, buckets=args.buckets,
+                      max_submission_retries=0)
     if args.warmup:
         eng.warmup()
     prof = Prof()
@@ -175,10 +211,11 @@ def main() -> int:
         # a concrete kernel config at warmup, then must reproduce the
         # untuned run's streams byte-for-byte
         eng2 = ServeEngine(cfg, params, n_slots=args.slots,
-                           budget=args.budget, prefill_impl="xla",
-                           paged=args.paged, page_size=args.page_size,
+                           budget=args.budget, paged=args.paged,
+                           page_size=args.page_size,
                            pool_pages=args.pool_pages,
-                           buckets=args.buckets, autotune=True)
+                           buckets=args.buckets, autotune=True,
+                           max_submission_retries=0)
         eng2.warmup()
         print(f"\nautotune: {len(eng2.autotune_events)} shape keys "
               f"resolved at warmup")
@@ -188,6 +225,11 @@ def main() -> int:
         assert streams2 == streams, \
             "autotuned engine streams diverge from untuned run"
         print("autotuned streams byte-identical to untuned run ✓")
+    failed = [s for s in eng.sequences if s.status is Status.FAILED]
+    if failed and args.deadline_ticks is None:
+        print(f"{len(failed)} of {len(reqs)} requests FAILED",
+              file=sys.stderr)
+        return 1
     return 0
 
 

@@ -14,6 +14,7 @@ import argparse
 import dataclasses
 
 from repro.configs import get_smoke_config
+from repro.core.compile_cache import use_compile_cache
 from repro.models.model import ModelConfig
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -45,6 +46,7 @@ def main() -> int:
                     help="inject a failure (demonstrates auto-resume)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = full_cfg() if args.full else small_cfg()
     from repro.models.model import param_count
     print(f"model: {cfg.name} ({param_count(cfg)[0]:,} params)")

@@ -9,7 +9,7 @@ from __future__ import annotations
 import argparse
 import sys
 
-from ..core import all_devices, available_platforms
+from ..core import ErrBox, available_platforms
 
 DEFAULT_KEYS = ["NAME", "PLATFORM", "KIND", "ID", "PROCESS_INDEX"]
 TARGET_KEYS = ["PEAK_BF16_FLOPS", "HBM_BANDWIDTH", "HBM_BYTES",
@@ -20,7 +20,8 @@ TARGET_KEYS = ["PEAK_BF16_FLOPS", "HBM_BANDWIDTH", "HBM_BYTES",
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description="repro device info")
     ap.add_argument("--all", action="store_true",
-                    help="include target-chip characteristics")
+                    help="include the chip's published peaks (core/hw.py; "
+                         "n/a for a device kind the table lacks)")
     ap.add_argument("--custom", nargs="*", default=None,
                     help="custom query: specific info keys only")
     args = ap.parse_args(argv)
@@ -35,7 +36,9 @@ def main(argv=None) -> int:
                 DEFAULT_KEYS + (TARGET_KEYS if args.all else []))
             print(f"  Device {dev.get_info('ID')}:")
             for k in keys:
-                print(f"    {k:22s} = {dev.get_info(k)}")
+                box = ErrBox()
+                val = dev.get_info(k, err=box)
+                print(f"    {k:22s} = {'n/a' if box.set else val}")
     return 0
 
 

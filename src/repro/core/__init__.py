@@ -23,7 +23,7 @@ Class map (cf4ocl → repro):
 from .buffer import Buffer, swap
 from .context import Context
 from .device import Device, all_devices
-from .device_selector import Filters, select_gpu_like
+from .device_selector import Filters, select_default_backend
 from .errors import Code, ErrBox, ReproError, err_string
 from .event import Event
 from .kernel import Kernel, suggest_batching, suggest_matmul_tiles
@@ -35,7 +35,7 @@ from . import hw, hlo_analysis
 
 __all__ = [
     "Buffer", "swap", "Context", "Device", "all_devices", "Filters",
-    "select_gpu_like", "Code", "ErrBox", "ReproError", "err_string",
+    "select_default_backend", "Code", "ErrBox", "ReproError", "err_string",
     "Event", "Kernel", "suggest_batching", "suggest_matmul_tiles",
     "Platform", "available_platforms", "platform_info", "Analysis",
     "Program", "DispatchQueue", "Wrapper", "live_wrappers", "memcheck",

@@ -19,7 +19,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from .device import Device
-from .device_selector import Filters, select_gpu_like
+from .device_selector import Filters, select_default_backend
 from .errors import Code, ErrBox, guard, raise_or_record
 from .wrapper import Wrapper
 
@@ -41,9 +41,10 @@ class Context(Wrapper):
     # -- constructors -------------------------------------------------------
     @classmethod
     def new_accel(cls, err: Optional[ErrBox] = None) -> Optional["Context"]:
-        """``ccl_context_new_gpu`` analogue: first accelerator-ish device(s)."""
+        """``ccl_context_new_gpu`` analogue: the devices of the backend
+        JAX was started on (``select_default_backend``)."""
         with guard(err) as g:
-            devs = select_gpu_like()
+            devs = select_default_backend()
             return cls(devs)
         return None
 

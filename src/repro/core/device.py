@@ -1,9 +1,10 @@
 """Device wrapper — ``CCLDevice`` analogue.
 
 Wraps :class:`jax.Device` one-to-one and answers info queries both about the
-*runtime* device (what jax reports) and about the *target* chip (the static
-:mod:`repro.core.hw` spec), since on this container runtime devices are CPU
-placeholders for a TPU v5e deployment.
+*runtime* device (what jax reports) and about its chip's published peaks
+(the static :mod:`repro.core.hw` table, keyed by ``device_kind``; a query
+fails for a kind the table lacks, such as the CPU).  ``target_spec`` is
+the chip the repository is written for, whatever the process runs on.
 """
 
 from __future__ import annotations
@@ -28,7 +29,7 @@ class Device(Wrapper):
             "PROCESS_INDEX": lambda d: d.process_index,
             "COORDS": lambda d: getattr(d, "coords", None),
             "MEMORY_STATS": Device._mem_stats,
-            # Target-chip characteristics (roofline constants)
+            # the chip's published peaks (roofline constants)
             "PEAK_BF16_FLOPS": lambda d: Device._spec(d).peak_bf16_flops,
             "HBM_BANDWIDTH": lambda d: Device._spec(d).hbm_bandwidth,
             "HBM_BYTES": lambda d: Device._spec(d).hbm_bytes,

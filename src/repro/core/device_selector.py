@@ -16,6 +16,7 @@ from typing import Callable, List, Optional, Sequence
 
 import jax
 
+from . import hw
 from .device import Device
 from .errors import Code, ErrBox, raise_or_record
 
@@ -52,7 +53,10 @@ class Filters:
         return self
 
     def min_hbm(self, nbytes: int) -> "Filters":
-        self._indep.append(lambda d: d.spec.hbm_bytes >= nbytes)
+        """Accept chips of a known kind (``hw.SPECS``) with at least
+        ``nbytes`` of HBM."""
+        self._indep.append(lambda d: d.kind in hw.SPECS and
+                           d.spec.hbm_bytes >= nbytes)
         return self
 
     # -- built-in dependent filters -----------------------------------------
@@ -101,14 +105,14 @@ class Filters:
         return devs
 
 
-def select_gpu_like(err: Optional[ErrBox] = None) -> List[Device]:
-    """``ccl_context_new_gpu`` device-selection part: prefer accelerators,
-    fall back to whatever exists (so CPU containers still work)."""
-    box = ErrBox()
-    devs = Filters().accelerator().select(err=box)
-    if box.set:
-        devs = Filters().select(err=err)
-    return devs
+def select_default_backend(err: Optional[ErrBox] = None) -> List[Device]:
+    """``ccl_context_new_gpu`` device-selection part: the devices of the
+    backend JAX was started on.  The backend is ``JAX_PLATFORMS``'s to
+    choose; nothing here second-guesses it or falls back to another, so a
+    run meant for the chip that came up on the CPU stays visibly on the
+    CPU."""
+    return Filters().type(jax.default_backend()).select(err=err)
 
 
-__all__ = ["Filters", "select_gpu_like", "IndepFilter", "DepFilter"]
+__all__ = ["Filters", "select_default_backend", "IndepFilter",
+           "DepFilter"]

@@ -1,15 +1,20 @@
 """Target-hardware constant tables.
 
-cf4ocl reads device capabilities through ``clGetDeviceInfo``; on this
-container the runtime devices are CPU stand-ins, so the *target* TPU
-capabilities come from a static spec table keyed by device kind.  The
-roofline engine (launch/rooofline) and ``Kernel.suggest_batching`` read from
-here — never hard-code these numbers elsewhere.
+cf4ocl reads device capabilities through ``clGetDeviceInfo``; JAX reports
+no peaks, so the chip's capabilities come from a static table keyed by the
+``device_kind`` JAX reports.  The roofline engine (launch/roofline) and
+``Kernel.suggest_batching`` read from here — never hard-code these numbers
+elsewhere.  A device kind missing from the table is an error, not a
+default: a guessed peak would make every roofline share built on it
+meaningless.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Dict
+
+from .errors import Code, ReproError
 
 
 @dataclasses.dataclass(frozen=True)
@@ -21,6 +26,7 @@ class ChipSpec:
     ici_link_bandwidth: float   # bytes/s per ICI link
     ici_links: int              # usable ICI links per chip (torus degree)
     vmem_bytes: int             # per-core VMEM
+    source: str                 # where the figures come from
     mxu_dim: int = 128          # systolic array edge
     vpu_lanes: int = 128        # vector lanes
     vpu_sublanes: int = 8
@@ -31,37 +37,33 @@ TPU_V5E = ChipSpec(
     peak_bf16_flops=197e12,
     hbm_bandwidth=819e9,
     hbm_bytes=16 * 1024**3,
+    # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
     ici_link_bandwidth=50e9,
     ici_links=4,
     vmem_bytes=128 * 1024**2,
+    source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+           '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI',
 )
 
-# CPU stand-in numbers only used so host runs produce finite estimates.
-CPU_HOST = ChipSpec(
-    name="cpu-host",
-    peak_bf16_flops=0.5e12,
-    hbm_bandwidth=50e9,
-    hbm_bytes=64 * 1024**3,
-    ici_link_bandwidth=10e9,
-    ici_links=1,
-    vmem_bytes=32 * 1024**2,
-)
-
-SPECS = {"tpu-v5e": TPU_V5E, "cpu-host": CPU_HOST}
+# keyed by ``jax.Device.device_kind`` exactly as JAX reports it
+SPECS: Dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
 
 
 def spec_for(device_kind: str) -> ChipSpec:
-    k = device_kind.lower()
-    if "tpu" in k and "v5" in k:
-        return TPU_V5E
-    if "cpu" in k or "host" in k:
-        # Target platform for this repo is v5e; CPU devices are placeholders
-        # for AOT analysis, so analysis paths use the TARGET spec and
-        # execution paths use CPU_HOST.  Callers choose explicitly.
-        return CPU_HOST
-    return TPU_V5E
+    """Peaks of the chip JAX names ``device_kind``; raises
+    ``DEVICE_NOT_FOUND`` for a kind the table does not hold (the CPU
+    among them: a host has no published peaks here)."""
+    try:
+        return SPECS[device_kind]
+    except KeyError:
+        raise ReproError(
+            Code.DEVICE_NOT_FOUND,
+            f"no chip spec for device kind {device_kind!r}; known kinds: "
+            f"{sorted(SPECS)}") from None
 
 
+# the chip this repository is written for (analysis paths size tiles and
+# rooflines against it, whatever device the process runs on)
 TARGET = TPU_V5E
 
-__all__ = ["ChipSpec", "TPU_V5E", "CPU_HOST", "SPECS", "spec_for", "TARGET"]
+__all__ = ["ChipSpec", "TPU_V5E", "SPECS", "spec_for", "TARGET"]

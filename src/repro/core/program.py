@@ -202,8 +202,6 @@ class Program(Wrapper):
                 self.compile()
             c = self._compiled
             ca = c.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):   # older jax: one dict per
-                ca = ca[0] if ca else {}        # partition, newest first
             ma = c.memory_analysis()
             txt = c.as_text()
             return Analysis(

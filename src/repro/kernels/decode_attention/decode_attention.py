@@ -12,9 +12,16 @@ One ``pallas_call`` performs, per decode step:
 
 Grid: ``(B, Hkv, S/block_kv)`` — all three dimensions parallel
 (flash-decode split-S).  Each cell emits a partial ``(acc, m, l)`` online
-softmax triple for its KV span; ``ops.py`` merges the splits with the
-standard cross-block combine.  This is the shape that keeps a 32k-entry
-cache attention on all cores instead of one sequential kv loop.
+softmax triple for its KV span; ``_combine_splits`` merges the splits with
+the standard cross-block combine.  This is the shape that keeps a
+32k-entry cache attention on all cores instead of one sequential kv loop.
+
+Layouts obey the chip's tiling rule (the last two dims of every block are
+divisible by (8, 128) or equal to the array's): the position plane is
+viewed as ``(B, nsplit, 1, block_kv)`` so each cell's ``(1, block_kv)``
+row is a whole trailing tile, and the partials are emitted as
+``(B, Hkv, nsplit, group, ·)`` so each cell writes a whole trailing
+``(group, ·)`` tile, for any ``nsplit``.
 
 The scalar-prefetch argument carries the per-sequence ``(2, B)`` plane
 ``[widx[b], pos[b]]`` so index maps and the in-block row select are known
@@ -38,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import LANES, NEG_INF, CompilerParams as _CompilerParams
+from ..common import LANES, NEG_INF, aligned_block
 
 
 def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, pos_ref,
@@ -65,7 +72,7 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, pos_ref,
     s = jax.lax.dot_general(
         q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale    # (group, block_kv)
-    kpos = pos_ref[...]                                # (1, block_kv)
+    kpos = pos_ref[0, 0]                               # (1, block_kv)
     mask = (kpos >= 0) & (kpos <= q_pos)
     if window is not None:
         mask &= kpos > q_pos - window
@@ -77,9 +84,9 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, pos_ref,
     acc = jax.lax.dot_general(
         p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # (group, d)
-    o_ref[0, :, 0, :] = acc
-    m_ref[0, :, 0, :] = jnp.broadcast_to(m, (m.shape[0], LANES))
-    l_ref[0, :, 0, :] = jnp.broadcast_to(l, (l.shape[0], LANES))
+    o_ref[0, 0, 0] = acc
+    m_ref[0, 0, 0] = jnp.broadcast_to(m, (m.shape[0], LANES))
+    l_ref[0, 0, 0] = jnp.broadcast_to(l, (l.shape[0], LANES))
 
 
 # Trace counter for the combine stage (tests assert the sweep-reuse
@@ -88,15 +95,18 @@ _combine_traces = 0
 
 
 def _combine_body(q, o_part, m_part, l_part):
+    """Partials are ``(B, Hkv, nsplit, group, ·)``; query head
+    ``h·group + g`` is row ``g`` of KV head ``h``."""
     global _combine_traces
     _combine_traces += 1
-    m = m_part[..., 0]                                 # (B, Hq, nsplit)
+    B, Hq, _, D = q.shape
+    m = m_part[..., 0]                                 # (B, Hkv, nsplit, g)
     l = l_part[..., 0]
-    m_glob = jnp.max(m, axis=-1, keepdims=True)
+    m_glob = jnp.max(m, axis=2, keepdims=True)
     alpha = jnp.exp(m - m_glob)
-    denom = jnp.maximum(jnp.sum(l * alpha, axis=-1), 1e-30)  # (B, Hq)
+    denom = jnp.maximum(jnp.sum(l * alpha, axis=2), 1e-30)  # (B, Hkv, g)
     out = jnp.sum(o_part * alpha[..., None], axis=2) / denom[..., None]
-    return out[:, :, None, :].astype(q.dtype)
+    return out.reshape(B, Hq, 1, D).astype(q.dtype)
 
 
 # Module-level jit: the combine's trace is keyed by the partial-tensor
@@ -139,9 +149,7 @@ def decode_attention_pallas(
     group = Hq // Hkv
     if scale is None:
         scale = D ** -0.5
-    block_kv = min(block_kv, S)
-    while S % block_kv:
-        block_kv -= 1
+    block_kv = aligned_block(S, block_kv)
     nsplit = S // block_kv
     grid = (B, Hkv, nsplit)
 
@@ -153,10 +161,12 @@ def decode_attention_pallas(
     kv_spec = pl.BlockSpec((1, 1, block_kv, D),
                            lambda b, h, s, i: (b, h, s, 0))
     new_spec = pl.BlockSpec((1, 1, 1, D), lambda b, h, s, i: (b, h, 0, 0))
-    pos_spec = pl.BlockSpec((1, block_kv), lambda b, h, s, i: (b, s))
-    o_spec = pl.BlockSpec((1, group, 1, D), lambda b, h, s, i: (b, h, s, 0))
-    ml_spec = pl.BlockSpec((1, group, 1, LANES),
-                           lambda b, h, s, i: (b, h, s, 0))
+    pos_spec = pl.BlockSpec((1, 1, 1, block_kv),
+                            lambda b, h, s, i: (b, s, 0, 0))
+    o_spec = pl.BlockSpec((1, 1, 1, group, D),
+                          lambda b, h, s, i: (b, h, s, 0, 0))
+    ml_spec = pl.BlockSpec((1, 1, 1, group, LANES),
+                           lambda b, h, s, i: (b, h, s, 0, 0))
 
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                block_kv=block_kv)
@@ -173,17 +183,20 @@ def decode_attention_pallas(
         out_shape=[
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-            jax.ShapeDtypeStruct((B, Hq, nsplit, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, nsplit, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, nsplit, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, group, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, group, LANES),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, group, LANES),
+                                 jnp.float32),
         ],
         # flattened arg indices include the scalar-prefetch array (0):
         # q=1, k_cache=2, v_cache=3 → outputs new_k=0, new_v=1
         input_output_aliases={2: 0, 3: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(idx, q, k_cache, v_cache, k_new, v_new, pos_cache)
+    )(idx, q, k_cache, v_cache, k_new, v_new,
+      pos_cache.reshape(B, nsplit, 1, block_kv))
 
     return _combine_splits(q, o_part, m_part, l_part), ok, ov
 
@@ -244,11 +257,12 @@ def decode_attention_paged_pallas(
     kv_spec = pl.BlockSpec((1, 1, ps, D),
                            lambda b, h, t, i, p: (p[b, t], h, 0, 0))
     new_spec = pl.BlockSpec((1, 1, 1, D), lambda b, h, t, i, p: (b, h, 0, 0))
-    pos_spec = pl.BlockSpec((1, ps), lambda b, h, t, i, p: (p[b, t], 0))
-    o_spec = pl.BlockSpec((1, group, 1, D),
-                          lambda b, h, t, i, p: (b, h, t, 0))
-    ml_spec = pl.BlockSpec((1, group, 1, LANES),
-                           lambda b, h, t, i, p: (b, h, t, 0))
+    pos_spec = pl.BlockSpec((1, 1, 1, ps),
+                            lambda b, h, t, i, p: (p[b, t], 0, 0, 0))
+    o_spec = pl.BlockSpec((1, 1, 1, group, D),
+                          lambda b, h, t, i, p: (b, h, t, 0, 0))
+    ml_spec = pl.BlockSpec((1, 1, 1, group, LANES),
+                           lambda b, h, t, i, p: (b, h, t, 0, 0))
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                window=window, block_kv=ps)
@@ -265,17 +279,20 @@ def decode_attention_paged_pallas(
         out_shape=[
             jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
             jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
-            jax.ShapeDtypeStruct((B, Hq, n_ptes, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, n_ptes, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, n_ptes, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_ptes, group, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_ptes, group, LANES),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_ptes, group, LANES),
+                                 jnp.float32),
         ],
         # flattened arg indices include both scalar-prefetch arrays
         # (idx=0, pt=1): q=2, k_arena=3, v_arena=4 → outputs 0, 1
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(idx, pt, q, k_arena, v_arena, k_new, v_new, pos_arena)
+    )(idx, pt, q, k_arena, v_arena, k_new, v_new,
+      pos_arena.reshape(n_pages, 1, 1, ps))
 
     return _combine_splits(q, o_part, m_part, l_part), ok, ov
 

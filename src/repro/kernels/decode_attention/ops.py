@@ -27,11 +27,10 @@ import jax
 import jax.numpy as jnp
 
 from ..autotune import decode_shape_key, get_autotuner
+from ..common import interpret_mode
 from .decode_attention import (decode_attention_paged_pallas,
                                decode_attention_pallas)
 from .ref import decode_attention_paged_ref, decode_attention_ref
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -62,7 +61,7 @@ def _decode_attention(q, k_cache, v_cache, pos_cache, k_new, v_new, pos,
     out, ok, ov = decode_attention_pallas(
         q, k_cache, v_cache, new_pos, k_new, v_new, widx, pos,
         window=window, scale=scale, block_kv=block_kv,
-        interpret=_INTERPRET)
+        interpret=interpret_mode())
     return out, ok, ov, new_pos
 
 
@@ -117,7 +116,7 @@ def _decode_attention_paged(q, k_arena, v_arena, pos_arena, k_new, v_new,
     new_pos = pos_arena.at[ppage, widx % ps].set(pos.astype(pos_arena.dtype))
     out, ok, ov = decode_attention_paged_pallas(
         q, k_arena, v_arena, new_pos, k_new, v_new, page_table, widx, pos,
-        window=window, scale=scale, interpret=_INTERPRET)
+        window=window, scale=scale, interpret=interpret_mode())
     return out, ok, ov, new_pos
 
 

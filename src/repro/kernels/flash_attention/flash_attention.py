@@ -4,8 +4,11 @@ TPU-native blocking (DESIGN.md §2): the kernel iterates a 4-D grid
 ``(batch, q_head, q_block, kv_block)`` with the kv dimension innermost and
 "arbitrary" semantics, keeping running softmax statistics in VMEM scratch
 (the FlashAttention online-softmax recurrence).  Block shapes are
-MXU-aligned: q/o tiles (block_q, d_head), k/v tiles (block_kv, d_head),
-d_head itself padded to a multiple of 128 by the wrapper when needed.
+q/o tiles (block_q, d_head), k/v tiles (block_kv, d_head); d_head is
+taken whole (no padding: a block dim equal to the array's is legal, so
+head_dim 64 runs as is).  ``block_q``/``block_kv`` are requests: the
+wrapper takes the largest multiple of 8 dividing the axis that does not
+exceed them, or the whole axis (``common.aligned_block``).
 
 Supports causal masking, sliding-window masking (Mistral/RecurrentGemma
 style) and GQA via index-map head division — one kernel serves the dense,
@@ -21,9 +24,12 @@ Two position modes:
   start-offset form is what lets prefix-shared prefill stay on Pallas.
   Causal/window whole-block skips are static in this mode.
 * **Explicit position planes** (``q_pos (B, T)``, ``k_pos (B, S)``
-  int32): positions are data, for the bucketed serve layouts where rows
-  are padded (``pos = -1`` masks a row/key out entirely) and spans are
-  non-contiguous (prefix pad + tail).  No static block skip — but every
+  int32, viewed as ``(B, T/block_q, 1, block_q)`` and
+  ``(B, S/block_kv, 1, block_kv)`` so each cell's ``(1, block)`` row is a
+  whole trailing tile for any batch): positions are data, for the
+  bucketed serve layouts where rows are padded (``pos = -1`` masks a
+  row/key out entirely) and spans are non-contiguous (prefix pad +
+  tail).  No static block skip — but every
   masked contribution is an exact no-op in the online-softmax update, so
   numerics match the arithmetic mode bit-for-bit on the same
   ``(S, block_kv)`` partition.
@@ -42,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import LANES, NEG_INF, CompilerParams as _CompilerParams
+from ..common import LANES, NEG_INF, aligned_block
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
@@ -63,8 +69,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
 
     if has_pos:
         # positions are data: padded rows/keys carry -1 and mask out
-        q_pos = qp_ref[...].reshape(block_q, 1)
-        k_pos = kp_ref[...].reshape(1, block_kv)
+        q_pos = qp_ref[0, 0].reshape(block_q, 1)
+        k_pos = kp_ref[0, 0]                          # (1, block_kv)
     else:
         # absolute positions from index arithmetic (queries start at
         # q_offset; the default q_offset = S - T puts them at the tail)
@@ -161,16 +167,10 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = D ** -0.5
     if q_offset is None:
         q_offset = S - T
-    # shrink to exact divisors: serve shapes are bucketed (page/tile
-    # aligned) so the ladder blocks divide; odd ad-hoc shapes still run
-    block_q = min(block_q, T)
-    while T % block_q:
-        block_q -= 1
-    block_kv = min(block_kv, S)
-    while S % block_kv:
-        block_kv -= 1
-    kv_steps = S // block_kv
-    grid = (B, Hq, T // block_q, kv_steps)
+    block_q = aligned_block(T, block_q)
+    block_kv = aligned_block(S, block_kv)
+    q_steps, kv_steps = T // block_q, S // block_kv
+    grid = (B, Hq, q_steps, kv_steps)
 
     q_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, i, j: (b, h, i, 0))
@@ -181,10 +181,13 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if has_pos:
-        in_specs += [pl.BlockSpec((1, block_q), lambda b, h, i, j: (b, i)),
-                     pl.BlockSpec((1, block_kv), lambda b, h, i, j: (b, j))]
-        operands += [jnp.asarray(q_pos, jnp.int32),
-                     jnp.asarray(k_pos, jnp.int32)]
+        in_specs += [pl.BlockSpec((1, 1, 1, block_q),
+                                  lambda b, h, i, j: (b, i, 0, 0)),
+                     pl.BlockSpec((1, 1, 1, block_kv),
+                                  lambda b, h, i, j: (b, j, 0, 0))]
+        operands += [
+            jnp.asarray(q_pos, jnp.int32).reshape(B, q_steps, 1, block_q),
+            jnp.asarray(k_pos, jnp.int32).reshape(B, kv_steps, 1, block_kv)]
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
@@ -202,7 +205,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, LANES), jnp.float32),   # l
             pltpu.VMEM((block_q, D), jnp.float32),       # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -24,10 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from ..autotune import flash_shape_key, get_autotuner
+from ..common import interpret_mode
 from .flash_attention import flash_attention_pallas
 from .ref import attention_pos_ref, attention_ref
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -47,7 +46,7 @@ def _flash_attention(q, k, v, q_pos, k_pos, causal: bool = True,
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, scale=scale,
         block_q=block_q, block_kv=block_kv, q_offset=q_offset,
-        q_pos=q_pos, k_pos=k_pos, interpret=_INTERPRET)
+        q_pos=q_pos, k_pos=k_pos, interpret=interpret_mode())
 
 
 def flash_attention(q, k, v, causal: bool = True,

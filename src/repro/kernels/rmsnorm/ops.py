@@ -7,10 +7,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..common import interpret_mode
 from .ref import rmsnorm_ref
 from .rmsnorm import rmsnorm_pallas
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "plus_one", "impl",
@@ -22,7 +21,7 @@ def rmsnorm(x, w, eps: float = 1e-6, plus_one: bool = False,
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     y = rmsnorm_pallas(x2, w, eps=eps, plus_one=plus_one,
-                       block_rows=block_rows, interpret=_INTERPRET)
+                       block_rows=block_rows, interpret=interpret_mode())
     return y.reshape(shape)
 
 

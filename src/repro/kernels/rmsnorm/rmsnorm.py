@@ -2,8 +2,11 @@
 
 One grid step normalizes a (block_rows, d) tile held in VMEM: the mean of
 squares, rsqrt and the gamma product fuse into a single VMEM-resident pass
-(vs. 3 HBM round-trips unfused).  d is expected 128-aligned (all configs in
-this repo are); block_rows adapts so the tile fits the VMEM budget.
+(vs. 3 HBM round-trips unfused).  The tile spans the whole feature axis
+``d`` (a block dim equal to the array's is legal at any width — SmolLM's
+d_model is 960, not a multiple of 128); ``block_rows`` is a request, and
+the wrapper takes the largest multiple of 8 dividing ``rows`` that does
+not exceed it, or all rows (``common.aligned_block``).
 """
 
 from __future__ import annotations
@@ -13,7 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from ..common import aligned_block
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, plus_one: bool):
@@ -32,9 +36,7 @@ def rmsnorm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6,
     """x: (rows, d), w: (d,) → (rows, d).  Caller flattens leading dims."""
     rows, d = x.shape
     assert w.shape == (d,)
-    block_rows = min(block_rows, rows)
-    while rows % block_rows:
-        block_rows //= 2
+    block_rows = aligned_block(rows, block_rows)
     grid = (rows // block_rows,)
     kernel = functools.partial(_rmsnorm_kernel, eps=eps, plus_one=plus_one)
     return pl.pallas_call(

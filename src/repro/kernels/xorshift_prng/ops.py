@@ -3,8 +3,9 @@
 API mirrors the example app's needs: ``prng_init(n)`` seeds state for ``n``
 64-bit values, ``prng_step(state)`` produces the next batch (Listing S5),
 ``to_uint64``/``to_uniform`` convert the (hi, lo) planes for consumers.
-On CPU containers the Pallas kernels run in ``interpret=True`` mode; on a
-real TPU the same BlockSpec'd kernels compile natively.
+On the CPU backend the Pallas kernels run in ``interpret=True`` mode
+(``common.interpret_mode``, asked at trace time); on a TPU the same
+BlockSpec'd kernels compile natively.
 """
 
 from __future__ import annotations
@@ -16,10 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import interpret_mode
 from . import ref as _ref
 from .xorshift_prng import DEFAULT_BLOCK_ROWS, LANES, init_pallas, rng_pallas
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 class PrngState(NamedTuple):
@@ -43,7 +43,7 @@ def _init(n: int, block_rows: int = DEFAULT_BLOCK_ROWS,
           use_pallas: bool = True) -> Tuple[jax.Array, jax.Array]:
     rows = _layout(n, block_rows)
     if use_pallas:
-        return init_pallas(n, rows, block_rows, interpret=_INTERPRET)
+        return init_pallas(n, rows, block_rows, interpret=interpret_mode())
     gids = (jnp.arange(rows * LANES, dtype=jnp.uint32).reshape(rows, LANES))
     hi, lo = _ref.init_ref(gids)
     live = gids < jnp.uint32(n)
@@ -61,7 +61,7 @@ def _step(hi: jax.Array, lo: jax.Array,
           block_rows: int = DEFAULT_BLOCK_ROWS,
           use_pallas: bool = True) -> Tuple[jax.Array, jax.Array]:
     if use_pallas:
-        return rng_pallas(hi, lo, block_rows, interpret=_INTERPRET)
+        return rng_pallas(hi, lo, block_rows, interpret=interpret_mode())
     return _ref.rng_ref(hi, lo)
 
 

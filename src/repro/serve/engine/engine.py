@@ -79,7 +79,9 @@ while the rest of the fleet streams on bit-identically:
 * **lane retry**: dispatch-queue submissions are retried with bounded
   exponential backoff; exhaustion surfaces ``SUBMISSION_FAILURE``
   through the per-request error channel (admission-side faults fail that
-  request only; a decode-lane exhaustion is batch-wide and fatal).
+  request only; a decode-lane exhaustion is batch-wide and fatal).  With
+  no retry policy (``max_submission_retries=0``) a lane error — a
+  compile or device failure — is not absorbed: it leaves ``step()``.
 
 All failure paths release resources exactly: pages decref'd (shared
 pages survive for their sharers), exclusive pages scrubbed and freed,
@@ -184,6 +186,12 @@ HISTOGRAM_METRICS = ("ttft_ticks", "tbt_ticks", "queue_wait_ticks",
 # per-tick gauges (last value + high-water mark); recorded while tracing
 GAUGE_METRICS = ("active_slots", "queue_depth", "pool_pages_held")
 
+# admission faults that fail only the request they hit.  Any other error
+# — a compile or device failure crossing a lane with no retry policy
+# (``max_submission_retries=0``) — propagates out of ``step()``.
+PER_REQUEST_CODES = frozenset({Code.SUBMISSION_FAILURE, Code.NUMERIC_FAULT,
+                               Code.OUT_OF_RESOURCES})
+
 
 class ServeEngine:
     def __init__(self, cfg: M.ModelConfig, params, *, n_slots: int = 4,
@@ -234,7 +242,9 @@ class ServeEngine:
         faults exercise every failure path.  Lane submissions are
         retried up to ``max_submission_retries`` times with exponential
         ``submission_backoff_s`` backoff before a structured
-        ``SUBMISSION_FAILURE`` surfaces.
+        ``SUBMISSION_FAILURE`` surfaces.  With 0 retries a lane error
+        is not a per-request fault: it propagates out of :meth:`step`
+        (``PER_REQUEST_CODES`` lists what admission absorbs).
 
         ``tracing`` (on by default) emits per-request lifecycle spans
         (``engine.trace``), links them to the device events that served
@@ -740,6 +750,8 @@ class ServeEngine:
                 try:
                     self._prefill_admit(seq, slot)
                 except ReproError as e:
+                    if e.code not in PER_REQUEST_CODES:
+                        raise
                     self._admit_fail(seq, slot, e)
                 admitted.append(seq)
             return admitted
@@ -805,6 +817,8 @@ class ServeEngine:
                 else:
                     self._prefill_admit(seq, slot, shared_toks, shared_ids)
             except ReproError as e:
+                if e.code not in PER_REQUEST_CODES:
+                    raise
                 self._admit_fail(seq, slot, e)
             admitted.append(seq)
         return admitted
@@ -1089,6 +1103,6 @@ class ServeEngine:
         self.q_decode.finish()
 
 
-__all__ = ["ServeEngine", "INSERT_EVENT", "PAGE_INSERT_EVENT",
-           "SWAP_OUT_EVENT", "SWAP_IN_EVENT", "SCRUB_EVENT",
-           "PREFIX_GATHER_EVENT", "COW_EVENT"]
+__all__ = ["ServeEngine", "PER_REQUEST_CODES", "INSERT_EVENT",
+           "PAGE_INSERT_EVENT", "SWAP_OUT_EVENT", "SWAP_IN_EVENT",
+           "SCRUB_EVENT", "PREFIX_GATHER_EVENT", "COW_EVENT"]

@@ -201,3 +201,54 @@ class TestHloAnalysis:
         ar = 2 * 128 * 4 * 255 // 256
         assert st.bytes_by_kind["all-gather"] == ag
         assert st.bytes_by_kind["all-reduce"] == ar
+
+
+class TestChipSpecs:
+    def test_known_kind(self):
+        spec = c.hw.spec_for("TPU v5 lite")
+        assert spec is c.hw.TPU_V5E
+        assert spec.peak_bf16_flops == 197e12 and spec.hbm_bandwidth == 819e9
+        assert "TPU v5e" in spec.source
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v5", ""])
+    def test_unknown_kind_raises(self, kind):
+        with pytest.raises(ReproError) as ei:
+            c.hw.spec_for(kind)
+        assert ei.value.code is Code.DEVICE_NOT_FOUND
+
+    def test_min_hbm_needs_a_known_kind(self):
+        box = ErrBox()
+        devs = c.Filters().min_hbm(1).select(err=box)
+        assert all(d.kind in c.hw.SPECS for d in devs)
+        assert box.set == (not devs)
+
+
+class TestDeviceSelection:
+    def test_new_accel_takes_the_started_backend(self):
+        ctx = c.Context.new_accel()
+        assert ctx.num_devices == len(jax.devices())
+        assert {d.platform for d in ctx.devices} == {jax.default_backend()}
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_var_wins(self, monkeypatch, tmp_path, restore_cache_dir):
+        from repro.core.compile_cache import use_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before   # untouched
+
+    def test_default_is_the_checkout(self, monkeypatch, restore_cache_dir):
+        import pathlib
+        from repro.core.compile_cache import use_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        want = str(root / ".jax_cache")
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want

@@ -28,6 +28,7 @@ from repro.ft.supervisor import Supervisor
 from repro.models import model as M
 from repro.models.model import ModelConfig
 from repro.serve.engine import Request, ServeEngine, Status
+from repro.serve.engine.engine import PER_REQUEST_CODES
 from repro.serve.step import (align_prefill_cache, make_decode_step,
                               make_prefill_step)
 
@@ -341,6 +342,21 @@ def test_retry_without_policy_keeps_legacy_wrapping():
 
     assert q2.enqueue(flaky) == 42
     assert q2.retries == 1
+
+
+def test_no_retry_policy_propagates_admission_lane_errors():
+    """With max_submission_retries=0 a lane error at admission is not a
+    per-request fault: it leaves step() with the original exception
+    chained, instead of turning the request FAILED."""
+    reqs = mk_trace()
+    plan = FaultPlan(lane_faults=(
+        LaneFault("Admit", "PREFILL_KERNEL", 0, 1),))
+    eng = mk_engine(HYBRID, plan=plan, max_submission_retries=0)
+    with pytest.raises(ReproError) as e:
+        eng.run(reqs)
+    assert e.value.code not in PER_REQUEST_CODES
+    assert isinstance(e.value.__cause__, InjectedFault)
+    assert not any(s.status is Status.FAILED for s in eng.sequences)
 
 
 # ------------------------------------------------- chaos conformance -------

@@ -202,3 +202,20 @@ def test_multistep_ring_wrap_consistency():
                                    atol=1e-5, rtol=1e-5,
                                    err_msg=f"step {t}")
     np.testing.assert_array_equal(np.asarray(pc_p), np.asarray(pc))
+
+
+@pytest.mark.parametrize("n,want,block", [
+    (37, 256, 37),        # fits: the whole axis
+    (2048, 256, 256),
+    (2048, 100, 64),      # largest multiple of 8 ≤ 100 dividing 2048
+    (1000, 256, 200),
+    (300, 256, 300),      # no multiple of 8 divides 300: the whole axis
+    (8, 4, 8),
+])
+def test_aligned_block(n, want, block):
+    """Block choice the chip's tiling accepts: a multiple of 8 dividing
+    the axis, or the whole axis — never an odd divisor like 37 of 74."""
+    from repro.kernels.common import aligned_block
+    got = aligned_block(n, want)
+    assert got == block
+    assert n % got == 0 and (got % 8 == 0 or got == n)
