@@ -1,0 +1,19 @@
+"""Roofline time of the window's decode attention (``roofline/
+decode_attn.py``, one call per layer per step over its rows' valid
+positions) over the device time of the paged decode kernel, in percent.
+Bound by bandwidth."""
+
+from lib.measure import DECODE_KERNEL
+from roofline import decode_attn
+
+
+def read(run):
+    if run.window is None:
+        return None
+    t = run.kernel_s(DECODE_KERNEL)
+    steps = run.decode_steps()
+    if not t or not steps:
+        return None
+    ideal = run.dims["layers"] * sum(
+        decode_attn.roofline_s(run.dims, run.peak, keys) for keys in steps)
+    return 100.0 * ideal / t

@@ -1,0 +1,18 @@
+"""Roofline time of the window's prefill attention (``roofline/
+flash_attn.py``, every layer of every admission) over the device time of
+the flash kernel, in percent.  Bound by compute at these lengths."""
+
+from lib.measure import FLASH_KERNEL
+from roofline import flash_attn
+
+
+def read(run):
+    if run.window is None:
+        return None
+    t = run.kernel_s(FLASH_KERNEL)
+    work = run.prefills()
+    if not t or not work:
+        return None
+    ideal = run.dims["layers"] * sum(
+        flash_attn.roofline_s(run.dims, run.peak, L, s) for L, s in work)
+    return 100.0 * ideal / t
