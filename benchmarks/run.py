@@ -955,137 +955,6 @@ def bench_elastic_batching():
         "tok_s_ratio": out["tok_s_ratio"]})
 
 
-# ----------------------------------------------------------------- E13 -----
-
-def bench_observability_overhead():
-    """Price of request-level tracing (spans, histograms, event linking).
-
-    The same Poisson trace is served by the paged engine — with pool
-    pressure, so the preemption/swap lifecycle states are exercised and
-    traced — once with ``tracing=False`` (counters only) and once with
-    the default ``tracing=True`` (span objects per lifecycle transition,
-    per-token DECODE spans, tick histograms, device-event linking).  No
-    behaviour may change: the streams must be byte-identical; the
-    measured gap is pure observability cost.  Best-of-reps decode
-    throughput; acceptance target < 2 % overhead (recorded as
-    ``tracing_lt_2pct``), lenient 10 % hard bound for noisy CI hosts.
-    The traced run must also produce at least one span per lifecycle
-    state the run exercised, with kernel events linked, and export
-    schema-valid Perfetto JSON.  Results land under the
-    ``observability_overhead`` key of BENCH_serve.json.
-    """
-    import jax
-    import numpy as np
-    from repro.models.model import ModelConfig, init_params
-    from repro.prof.export import export_perfetto
-    from repro.serve.engine import Request, ServeEngine
-
-    cfg = ModelConfig(name="bench-obs", family="dense", num_layers=2,
-                      d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-                      d_ff=128, vocab=256, dtype="float32")
-    n_slots, budget, reps = 4, 48, 9
-    key = jax.random.PRNGKey(0)
-    params = init_params(cfg, key)
-
-    # mixed-length trace (near-budget prompts next to short chats): at
-    # 20 pool pages this oversubscribes the arena, so preemption and
-    # swap-in states are exercised and traced, not just the happy path
-    rng = np.random.default_rng(11)
-    arrivals = np.cumsum(rng.poisson(1.5, size=16))
-    reqs = []
-    for i, a in enumerate(arrivals):
-        L = int(rng.integers(4, 33))
-        n = int(rng.integers(4, min(17, budget - L + 1)))
-        reqs.append(Request(i, [int(t) for t in rng.integers(0, cfg.vocab,
-                                                             L)],
-                            n, arrival=int(a)))
-
-    def serve(tracing):
-        eng = ServeEngine(cfg, params, n_slots=n_slots, budget=budget,
-                          paged=True, page_size=4, pool_pages=20,
-                          tracing=tracing)
-        streams = eng.run(reqs)
-        return eng, streams
-
-    out = {"backend": jax.default_backend(),
-           "trace": {"n_requests": len(reqs), "n_slots": n_slots,
-                     "budget": budget, "pool_pages": 20, "reps": reps},
-           "rows": []}
-    modes = [("tracing_off", False), ("tracing_on", True)]
-    streams_by, tok_s_by, engs = {}, {}, {}
-    best = {name: None for name, _ in modes}
-    for _, tracing in modes:
-        serve(tracing)                          # warmup (jit compile)
-    # interleave the reps (off, on, off, on, …): a host-load drift then
-    # hits both modes alike instead of inflating whichever block ran
-    # second, and best-of-reps discards the disturbed pairs
-    for _ in range(reps):
-        for name, tracing in modes:
-            t0 = time.perf_counter()
-            eng, streams = serve(tracing)
-            dt = time.perf_counter() - t0
-            if best[name] is None or dt < best[name]:
-                best[name] = dt
-            engs[name], streams_by[name] = eng, streams
-    eng_on = engs["tracing_on"]
-    for name, tracing in modes:
-        decoded = engs[name].stats["decoded_tokens"]
-        tok_s_by[name] = decoded / best[name]
-        row = {"policy": name, "decoded_tokens": decoded,
-               "tok_s": tok_s_by[name], "wall_s": best[name]}
-        if tracing:
-            row.update(_latency_cols(engs[name]))
-        out["rows"].append(row)
-        print(f"# {name}: {decoded} decode tokens in {best[name]:.3f}s "
-              f"({tok_s_by[name]:,.1f} tok/s)", file=sys.stderr)
-        _emit(f"observability_overhead_{name}", best[name] * 1e6,
-              f"tok_s={tok_s_by[name]:.1f}")
-    out["streams_match"] = streams_by["tracing_off"] == \
-        streams_by["tracing_on"]
-    out["overhead_frac"] = max(
-        0.0, 1.0 - tok_s_by["tracing_on"] / tok_s_by["tracing_off"])
-    out["tracing_lt_2pct"] = out["overhead_frac"] < 0.02
-    print(f"# streams_match={out['streams_match']} tracing overhead "
-          f"{out['overhead_frac'] * 100:.2f}% "
-          f"(<2%: {out['tracing_lt_2pct']})", file=sys.stderr)
-    assert out["streams_match"], "tracing changed the streams!"
-    assert out["overhead_frac"] < 0.10, \
-        f"tracing costs {out['overhead_frac'] * 100:.1f}% decode tok/s"
-
-    # coverage: one span per lifecycle state the run exercised, every
-    # trace contiguous, kernel events linked into the spans
-    trace = eng_on.trace
-    kinds = {k.value for k in trace.span_kinds()}
-    expected = {"QUEUED", "PREFILL", "DECODE"}
-    if eng_on.stats["preemptions"]:
-        expected |= {"PREEMPTED"}
-    if eng_on.stats["swap_ins"]:
-        expected |= {"SWAP"}
-    assert expected <= kinds, f"missing span kinds: {expected - kinds}"
-    for rt in trace:
-        assert rt.contiguous(), f"rid {rt.rid}: non-contiguous spans"
-    linked = {e.name for rt in trace for s in rt.spans for e in s.events}
-    assert "PREFILL_KERNEL" in linked and "DECODE_KERNEL" in linked, \
-        f"kernel events not linked into spans: {linked}"
-    out["span_kinds"] = sorted(kinds)
-    out["linked_event_names"] = sorted(linked)
-
-    # export must be schema-valid Chrome trace_event JSON
-    doc = json.loads(export_perfetto(None, trace=trace))
-    assert all(k in e for e in doc["traceEvents"]
-               for k in ("ph", "ts", "pid", "tid"))
-    out["perfetto_events"] = len(doc["traceEvents"])
-    print(f"# span kinds {out['span_kinds']}, "
-          f"{out['perfetto_events']} perfetto events", file=sys.stderr)
-    _merge_snapshot(ROOT / "BENCH_serve.json",
-                    {"observability_overhead": out})
-    _history_append("observability_overhead", {
-        "rows": out["rows"], "streams_match": out["streams_match"],
-        "overhead_frac": out["overhead_frac"],
-        "tracing_lt_2pct": out["tracing_lt_2pct"],
-        "span_kinds": out["span_kinds"]})
-
-
 BENCHES = {
     "loc_compare": bench_loc_compare,
     "overhead": bench_overhead,
@@ -1099,7 +968,6 @@ BENCHES = {
     "prefix_sharing": bench_prefix_sharing,
     "fault_overhead": bench_fault_overhead,
     "elastic_batching": bench_elastic_batching,
-    "observability_overhead": bench_observability_overhead,
 }
 
 

@@ -8,7 +8,7 @@ from .metrics import (DEFAULT_TICK_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, StatsView)
 from .profiler import (InstType, Prof, ProfAgg, ProfInfo, ProfInst,
                        ProfOverlap, Sort)
-from .trace import RequestTrace, Span, SpanKind, TraceCollector
+from .trace import RequestTrace, Span, SpanKind, TraceCollector, host_span
 
 __all__ = [
     "Prof", "ProfAgg", "ProfInfo", "ProfInst", "ProfOverlap", "InstType",
@@ -16,5 +16,5 @@ __all__ = [
     "render_queue_chart", "perfetto_trace", "export_perfetto",
     "render_request_gantt", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "StatsView", "DEFAULT_TICK_BUCKETS", "SpanKind",
-    "Span", "RequestTrace", "TraceCollector",
+    "Span", "RequestTrace", "TraceCollector", "host_span",
 ]

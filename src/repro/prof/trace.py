@@ -36,6 +36,15 @@ with the device events' clocks).
 ``PAGE_COW``, ``TRACE_COMPILE``, …) via :meth:`TraceCollector.link`, so
 a slow request points straight at the device work that made it slow —
 the cf4ocl event-timeline idea extended across the request boundary.
+
+**Host phases on the profiler's clock**: :func:`host_span` names one
+stretch of the engine's host work (``serve.step``, ``serve.decode``,
+``serve.sample``, …) as a ``jax.profiler.TraceAnnotation``.  The span
+lands in the profiler's own host trace, on the clock the device events
+are aligned to, so a gap in which the device sat idle can be put down to
+what the host was doing.  The ``now_ns`` stamps above are
+``perf_counter`` instants and do not line up with that trace; a span
+that belongs to a request carries its ``rid`` as the join key.
 """
 
 from __future__ import annotations
@@ -44,7 +53,18 @@ import dataclasses
 import enum
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
+
 from ..core.event import Event, now_ns
+
+
+def host_span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` on the profiler's clock, used as a context
+    manager; ``stats`` (numbers or strings) are recorded with it, and
+    ``set_metadata`` adds more before it closes.  With no profiler
+    session running the span records nothing and costs about a
+    microsecond."""
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
 class SpanKind(enum.Enum):
@@ -211,15 +231,11 @@ class TraceCollector:
     def __len__(self) -> int:
         return len(self.traces)
 
-    def span_kinds(self) -> set:
-        """Set of SpanKinds present across every trace (the E13 bench's
-        lifecycle-coverage check)."""
-        return {s.kind for rt in self for s in rt.spans}
-
     def time_range_ns(self) -> Optional[Tuple[int, int]]:
         ts = [t for rt in self for s in rt.spans
               for t in (s.t0, s.t1) if t is not None]
         return (min(ts), max(ts)) if ts else None
 
 
-__all__ = ["SpanKind", "Span", "RequestTrace", "TraceCollector"]
+__all__ = ["SpanKind", "Span", "RequestTrace", "TraceCollector",
+           "host_span"]

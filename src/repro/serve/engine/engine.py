@@ -128,8 +128,13 @@ and per-tick gauges.  ``engine.stats`` is a live
 ``stats.percentile("ttft_ticks", 99)`` / ``stats.snapshot()`` expose
 the SLO numbers benches report.  ``tracing=False`` skips span objects,
 histogram observations and event linking (counters stay on — they are
-the stats surface); benchmark E13 prices the difference at < 2 % decode
-tok/s with byte-identical streams.
+the stats surface); streams are byte-identical either way.  Separately,
+every phase of a tick is a ``serve.*`` host span on the JAX profiler's
+clock (``prof.trace.host_span``; DESIGN.md "Host phases on the
+profiler's clock"), so device idle time in a profiler trace is put down
+to what the host was doing.  The spans are always emitted: with no
+profiler running they cost about 5 µs a tick on a TPU v5e host (0.44
+µs a span, 0.60 µs with a stat), 0.01 % of a 37–55 ms tick.
 
 Simplifications (documented, not accidental): greedy sampling unless a
 ``sample_fn`` is supplied; one prefill per admission; the per-tick host
@@ -150,7 +155,7 @@ from ...core import Context, DispatchQueue
 from ...core.errors import Code, ReproError, err_string
 from ...models import model as M
 from ...prof.metrics import MetricsRegistry, StatsView
-from ...prof.trace import SpanKind, TraceCollector
+from ...prof.trace import SpanKind, TraceCollector, host_span
 from .. import paging as P
 from ..step import (ALIGN_EVENT, DECODE_EVENT, PREFILL_EVENT,
                     TRACE_AUTOTUNE_EVENT, BucketRegistry)
@@ -249,8 +254,9 @@ class ServeEngine:
         ``tracing`` (on by default) emits per-request lifecycle spans
         (``engine.trace``), links them to the device events that served
         them, and records the tick-based latency histograms/gauges;
-        turning it off keeps only the counters (benchmark E13 prices the
-        difference — byte-identical streams either way)."""
+        turning it off keeps only the counters (byte-identical streams
+        either way).  The ``serve.*`` profiler spans do not depend on
+        it."""
         assert not cfg.has_cross, \
             "serve engine does not support cross-attention models"
         self.cfg = cfg
@@ -584,101 +590,105 @@ class ServeEngine:
         L = seq.prompt_len
         if self.trace is not None:
             self.trace.transition(seq.rid, SpanKind.PREFILL, self.tick)
-        if shared_toks:
-            # prefix sharing: gather the resident shared span back into
-            # prefill layout and prefill only the unshared tail — both
-            # on the Admit lane, so the gather orders after the donor's
-            # own page inserts and the partial prefill after the gather.
-            # The page-id run is padded to its power-of-two bucket with
-            # null pages (pos = -1, masked) so the gather and the
-            # partial prefill compile once per bucket pair, not once per
-            # (prefix, tail) length pair.
-            seq.shared_tokens = shared_toks
-            self.metrics.inc("prefix_hits")
-            self.metrics.inc("shared_tokens", shared_toks)
-            m = shared_toks // self.page_size
-            m_b = reg.page_bucket(m)
-            pad_ids = {}
-            for k, v in shared_ids.items():
-                row = np.full(m_b, P.PAGE_NULL, np.int32)
-                row[:m] = v
-                pad_ids[k] = jnp.asarray(row)
-            prefix = self.q_admit.enqueue(
-                paged_gather_jit, self.cfg, self.cache_mgr.cache, pad_ids,
-                name=PREFIX_GATHER_EVENT, command_type=PREFIX_GATHER_EVENT)
-            self._link(seq, self.q_admit)
-            prefix_pad = m_b * self.page_size
-            tail_len = reg.len_bucket(L - shared_toks)
-            tail = np.zeros((1, tail_len), np.int32)
-            tail[0, :L - shared_toks] = tokens[shared_toks:]
-            logits, cache = self.q_admit.enqueue(
-                reg.prefill_ext(prefix_pad, tail_len), self.params,
-                jnp.asarray(tail), prefix, jnp.int32(shared_toks),
-                jnp.int32(L),
-                name=PREFILL_EVENT, command_type=PREFILL_EVENT)
-            self._link(seq, self.q_admit)
-            ring_len = prefix_pad + tail_len
-        else:
-            ring_len = reg.len_bucket(L)
-            prefix_pad = 0
-            prompt = np.zeros((1, ring_len), np.int32)
-            prompt[0, :L] = tokens
-            logits, cache = self.q_admit.enqueue(
-                reg.prefill(ring_len), self.params, jnp.asarray(prompt),
-                jnp.int32(L),
-                name=PREFILL_EVENT, command_type=PREFILL_EVENT)
-            self._link(seq, self.q_admit)
-        self.metrics.inc("prefill_tokens", seq.prompt_len - shared_toks)
-        # relayout and slot packing are enqueued as *pure* jitted fns
-        # whose outputs are the events' outputs — finish() fences
-        # them and the spans track the copies, not host dispatch
-        align = reg.align(ring_len, prefix_pad)
-        if self.paged:
-            blocks = self.q_admit.enqueue(
-                align, cache, jnp.int32(L), jnp.int32(shared_toks),
-                name=ALIGN_EVENT, command_type=ALIGN_EVENT)
-            self._link(seq, self.q_admit)
-            ids = self.cache_mgr.table_ids(slot)
+        with host_span("serve.prefill",
+                       bucket=reg.len_bucket(L - shared_toks)):
             if shared_toks:
-                # donation skips the shared span: its blocks sink into
-                # the null page — the resident copies are already
-                # canonical and a scatter into them would be a write to
-                # refcount>1 pages
-                for kind in ids:
-                    ids[kind][:shared_toks // self.page_size] = P.PAGE_NULL
-            packed = self.q_admit.enqueue(
-                paged_insert_jit, self.cfg, self.cache_mgr.cache, blocks,
-                ids, jnp.int32(slot),
-                name=PAGE_INSERT_EVENT, command_type=PAGE_INSERT_EVENT)
-            self._link(seq, self.q_admit)
-        else:
-            cache = self.q_admit.enqueue(
-                align, cache, jnp.int32(L), jnp.int32(0),
-                name=ALIGN_EVENT, command_type=ALIGN_EVENT)
-            self._link(seq, self.q_admit)
-            packed = self.q_admit.enqueue(
-                insert_jit, self.cache_mgr.cache, cache, jnp.int32(slot),
-                name=INSERT_EVENT, command_type=INSERT_EVENT)
-            self._link(seq, self.q_admit)
-        self.cache_mgr.update(packed)
-        if self.paged:
-            # publish this prompt's full-page blocks for later arrivals
-            # (host-side; the content lands via Admit-lane ordering);
-            # the sequence's chain reuses the admission-time hashes
-            self.cache_mgr.register_prefix(slot, tokens,
-                                           chain=seq.prefix_chain)
+                # prefix sharing: gather the resident shared span back into
+                # prefill layout and prefill only the unshared tail — both
+                # on the Admit lane, so the gather orders after the donor's
+                # own page inserts and the partial prefill after the gather.
+                # The page-id run is padded to its power-of-two bucket with
+                # null pages (pos = -1, masked) so the gather and the
+                # partial prefill compile once per bucket pair, not once per
+                # (prefix, tail) length pair.
+                seq.shared_tokens = shared_toks
+                self.metrics.inc("prefix_hits")
+                self.metrics.inc("shared_tokens", shared_toks)
+                m = shared_toks // self.page_size
+                m_b = reg.page_bucket(m)
+                pad_ids = {}
+                for k, v in shared_ids.items():
+                    row = np.full(m_b, P.PAGE_NULL, np.int32)
+                    row[:m] = v
+                    pad_ids[k] = jnp.asarray(row)
+                prefix = self.q_admit.enqueue(
+                    paged_gather_jit, self.cfg, self.cache_mgr.cache, pad_ids,
+                    name=PREFIX_GATHER_EVENT, command_type=PREFIX_GATHER_EVENT)
+                self._link(seq, self.q_admit)
+                prefix_pad = m_b * self.page_size
+                tail_len = reg.len_bucket(L - shared_toks)
+                tail = np.zeros((1, tail_len), np.int32)
+                tail[0, :L - shared_toks] = tokens[shared_toks:]
+                logits, cache = self.q_admit.enqueue(
+                    reg.prefill_ext(prefix_pad, tail_len), self.params,
+                    jnp.asarray(tail), prefix, jnp.int32(shared_toks),
+                    jnp.int32(L),
+                    name=PREFILL_EVENT, command_type=PREFILL_EVENT)
+                self._link(seq, self.q_admit)
+                ring_len = prefix_pad + tail_len
+            else:
+                ring_len = reg.len_bucket(L)
+                prefix_pad = 0
+                prompt = np.zeros((1, ring_len), np.int32)
+                prompt[0, :L] = tokens
+                logits, cache = self.q_admit.enqueue(
+                    reg.prefill(ring_len), self.params, jnp.asarray(prompt),
+                    jnp.int32(L),
+                    name=PREFILL_EVENT, command_type=PREFILL_EVENT)
+                self._link(seq, self.q_admit)
+        self.metrics.inc("prefill_tokens", seq.prompt_len - shared_toks)
+        with host_span("serve.align"):
+            # relayout and slot packing are enqueued as *pure* jitted fns
+            # whose outputs are the events' outputs — finish() fences
+            # them and the spans track the copies, not host dispatch
+            align = reg.align(ring_len, prefix_pad)
+            if self.paged:
+                blocks = self.q_admit.enqueue(
+                    align, cache, jnp.int32(L), jnp.int32(shared_toks),
+                    name=ALIGN_EVENT, command_type=ALIGN_EVENT)
+                self._link(seq, self.q_admit)
+                ids = self.cache_mgr.table_ids(slot)
+                if shared_toks:
+                    # donation skips the shared span: its blocks sink into
+                    # the null page — the resident copies are already
+                    # canonical and a scatter into them would be a write to
+                    # refcount>1 pages
+                    for kind in ids:
+                        ids[kind][:shared_toks // self.page_size] = P.PAGE_NULL
+                packed = self.q_admit.enqueue(
+                    paged_insert_jit, self.cfg, self.cache_mgr.cache, blocks,
+                    ids, jnp.int32(slot),
+                    name=PAGE_INSERT_EVENT, command_type=PAGE_INSERT_EVENT)
+                self._link(seq, self.q_admit)
+            else:
+                cache = self.q_admit.enqueue(
+                    align, cache, jnp.int32(L), jnp.int32(0),
+                    name=ALIGN_EVENT, command_type=ALIGN_EVENT)
+                self._link(seq, self.q_admit)
+                packed = self.q_admit.enqueue(
+                    insert_jit, self.cache_mgr.cache, cache, jnp.int32(slot),
+                    name=INSERT_EVENT, command_type=INSERT_EVENT)
+                self._link(seq, self.q_admit)
+            self.cache_mgr.update(packed)
+            if self.paged:
+                # publish this prompt's full-page blocks for later arrivals
+                # (host-side; the content lands via Admit-lane ordering);
+                # the sequence's chain reuses the admission-time hashes
+                self.cache_mgr.register_prefix(slot, tokens,
+                                               chain=seq.prefix_chain)
         self.metrics.inc("prefills")
         if self.trace is not None:
             # any bucket that compiled during this admission served it
             self.trace.link(seq.rid, *self._drain_compiles())
         seq.pos = seq.prompt_len
-        # first output token comes from the prefill logits
-        lg = np.asarray(logits[:, -1])
-        if self.guards and not np.isfinite(lg).all():
-            raise ReproError(
-                Code.NUMERIC_FAULT,
-                f"request {seq.rid}: non-finite prefill logits")
-        t0 = int(self._sample(lg)[0])
+        with host_span("serve.first_token"):
+            # first output token comes from the prefill logits
+            lg = np.asarray(logits[:, -1])
+            if self.guards and not np.isfinite(lg).all():
+                raise ReproError(
+                    Code.NUMERIC_FAULT,
+                    f"request {seq.rid}: non-finite prefill logits")
+            t0 = int(self._sample(lg)[0])
         self._bind(seq, slot, t0)
 
     def _swap_in(self, seq: Sequence, slot: int,
@@ -747,12 +757,16 @@ class ServeEngine:
         if not self.paged:
             admitted = []
             for seq, slot in self.scheduler.admit():
-                try:
-                    self._prefill_admit(seq, slot)
-                except ReproError as e:
-                    if e.code not in PER_REQUEST_CODES:
-                        raise
-                    self._admit_fail(seq, slot, e)
+                with host_span("serve.admit", rid=seq.rid,
+                               prompt_len=seq.prompt_len,
+                               shared_tokens=0) as span:
+                    try:
+                        self._prefill_admit(seq, slot)
+                    except ReproError as e:
+                        if e.code not in PER_REQUEST_CODES:
+                            raise
+                        self._admit_fail(seq, slot, e)
+                    self._note_queue_wait(span, seq)
                 admitted.append(seq)
             return admitted
         # paged: gate each admission on pages free, not just slots free.
@@ -808,20 +822,41 @@ class ServeEngine:
                 if not self._slot_seq and self._spill_one_pin(head):
                     continue
                 break
-            seq, slot = self.scheduler.pop_bind()
-            ok = self.cache_mgr.admit_pages(slot, need, shared=shared_ids)
-            assert ok, "gate passed but allocation failed"
-            try:
-                if resume:
-                    self._swap_in(seq, slot, shared_toks)
-                else:
-                    self._prefill_admit(seq, slot, shared_toks, shared_ids)
-            except ReproError as e:
-                if e.code not in PER_REQUEST_CODES:
-                    raise
-                self._admit_fail(seq, slot, e)
+            # a resumption is no admission: it restores a swapped-out
+            # sequence, so it gets a span of its own
+            span = host_span("serve.swap_in", rid=head.rid) if resume \
+                else host_span("serve.admit", rid=head.rid,
+                               prompt_len=head.prompt_len,
+                               shared_tokens=shared_toks)
+            with span:
+                seq, slot = self.scheduler.pop_bind()
+                ok = self.cache_mgr.admit_pages(slot, need,
+                                                shared=shared_ids)
+                assert ok, "gate passed but allocation failed"
+                try:
+                    if resume:
+                        self._swap_in(seq, slot, shared_toks)
+                    else:
+                        self._prefill_admit(seq, slot, shared_toks,
+                                            shared_ids)
+                except ReproError as e:
+                    if e.code not in PER_REQUEST_CODES:
+                        raise
+                    self._admit_fail(seq, slot, e)
+                if not resume:
+                    self._note_queue_wait(span, seq)
             admitted.append(seq)
         return admitted
+
+    def _note_queue_wait(self, span, seq: Sequence) -> None:
+        """Record on an admission's host span how long ``seq`` sat
+        queued, in µs: the duration of its ``QUEUED`` span, so the
+        request trace (``perf_counter`` clock) joins the profiler's trace
+        by ``rid`` and this number."""
+        if self.trace is not None:
+            q = self.trace.traces[seq.rid].spans[0]
+            if q.t1 is not None:
+                span.set_metadata(queue_wait_us=(q.t1 - q.t0) // 1000)
 
     def _spill_one_pin(self, head: Sequence) -> bool:
         """Release one preempted sequence's pinned prefix pages to
@@ -960,47 +995,53 @@ class ServeEngine:
     def _decode_tick(self) -> List[Sequence]:
         finished: List[Sequence] = []
         if self.paged:
-            finished += self._provision()
-            self.cache_mgr.sync()
+            with host_span("serve.provision") as span:
+                cow0 = self.stats["cow_copies"]
+                finished += self._provision()
+                span.set_metadata(cow_pages=self.stats["cow_copies"] - cow0)
+            with host_span("serve.table_sync"):
+                self.cache_mgr.sync()
         active = sorted(self._slot_seq)
         if not active:
             return finished
         width = self._registry.width_bucket(len(active))
-        if width < self.n_slots:
-            # pack the active slots into the smallest covering width
-            # bucket: dense (W,) tokens/positions/rows in, per-slot
-            # results scattered back inside the jitted step.  Padding
-            # rows carry the out-of-bounds sentinel n_slots and behave
-            # exactly like idle slots of the full-width path.
-            na = len(active)
-            rows = np.full((width,), self.n_slots, np.int32)
-            rows[:na] = active
-            tok = np.zeros((width, 1), np.int32)
-            tok[:na] = self._tokens[active]
-            pos = np.full((width,), -1, np.int32)
-            pos[:na] = self._pos[active]
-            logits, cache = self.q_decode.enqueue(
-                self._registry.decode(width), self.params,
-                self.cache_mgr.cache, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(rows),
-                name=DECODE_EVENT, command_type=DECODE_EVENT)
+        na = len(active)
+        with host_span("serve.decode", width=width, active=na):
+            if width < self.n_slots:
+                # pack the active slots into the smallest covering width
+                # bucket: dense (W,) tokens/positions/rows in, per-slot
+                # results scattered back inside the jitted step.  Padding
+                # rows carry the out-of-bounds sentinel n_slots and behave
+                # exactly like idle slots of the full-width path.
+                rows = np.full((width,), self.n_slots, np.int32)
+                rows[:na] = active
+                tok = np.zeros((width, 1), np.int32)
+                tok[:na] = self._tokens[active]
+                pos = np.full((width,), -1, np.int32)
+                pos[:na] = self._pos[active]
+                logits, cache = self.q_decode.enqueue(
+                    self._registry.decode(width), self.params,
+                    self.cache_mgr.cache, jnp.asarray(tok),
+                    jnp.asarray(pos), jnp.asarray(rows),
+                    name=DECODE_EVENT, command_type=DECODE_EVENT)
+            else:
+                logits, cache = self.q_decode.enqueue(
+                    self._registry.decode_full(), self.params,
+                    self.cache_mgr.cache,
+                    jnp.asarray(self._tokens), jnp.asarray(self._pos),
+                    name=DECODE_EVENT, command_type=DECODE_EVENT)
             self.cache_mgr.update(cache)
             self.metrics.inc("decode_steps")
-            packed_lg = np.asarray(logits[:, 0])          # (W, V)
-            # expand to slot-indexed logits so sampling, fault injection
-            # and the quarantine stay on logical slots
-            lg = np.zeros((self.n_slots,) + packed_lg.shape[1:],
-                          packed_lg.dtype)
-            lg[active] = packed_lg[:na]
-        else:
-            logits, cache = self.q_decode.enqueue(
-                self._registry.decode_full(), self.params,
-                self.cache_mgr.cache,
-                jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                name=DECODE_EVENT, command_type=DECODE_EVENT)
-            self.cache_mgr.update(cache)
-            self.metrics.inc("decode_steps")
-            lg = np.asarray(logits[:, 0])                 # (n_slots, V)
+        with host_span("serve.logits_to_host"):
+            if width < self.n_slots:
+                packed_lg = np.asarray(logits[:, 0])          # (W, V)
+                # expand to slot-indexed logits so sampling, fault
+                # injection and the quarantine stay on logical slots
+                lg = np.zeros((self.n_slots,) + packed_lg.shape[1:],
+                              packed_lg.dtype)
+                lg[active] = packed_lg[:na]
+            else:
+                lg = np.asarray(logits[:, 0])             # (n_slots, V)
         decode_ev = None
         if self.trace is not None:
             decode_ev = self.q_decode.last_event()
@@ -1012,51 +1053,56 @@ class ServeEngine:
         if self._plan is not None:
             lg = self._plan.corrupt_logits(lg, self.tick)
         if self.guards:
-            # NaN/Inf quarantine: fail only the poisoned slots, *before*
-            # sampling streams a garbage token — the failed stream stays
-            # a clean prefix of its fault-free oracle and every other
-            # slot decodes on unperturbed
-            for slot in list(active):
-                if not np.isfinite(lg[slot]).all():
-                    seq = self._slot_seq[slot]
-                    self._fail(seq, ReproError(
-                        Code.NUMERIC_FAULT,
-                        f"request {seq.rid}: non-finite decode logits "
-                        f"at tick {self.tick} (slot {slot})"))
+            with host_span("serve.guard"):
+                # NaN/Inf quarantine: fail only the poisoned slots,
+                # *before* sampling streams a garbage token — the failed
+                # stream stays a clean prefix of its fault-free oracle and
+                # every other slot decodes on unperturbed
+                for slot in list(active):
+                    if not np.isfinite(lg[slot]).all():
+                        seq = self._slot_seq[slot]
+                        self._fail(seq, ReproError(
+                            Code.NUMERIC_FAULT,
+                            f"request {seq.rid}: non-finite decode logits "
+                            f"at tick {self.tick} (slot {slot})"))
+                        finished.append(seq)
+                        active.remove(slot)
+        with host_span("serve.sample"):
+            nxt = self._sample(lg)                        # (n_slots,)
+        with host_span("serve.emit"):
+            for slot in active:
+                seq = self._slot_seq[slot]
+                tok = int(nxt[slot])
+                seq.pos += 1
+                self.metrics.inc("decoded_tokens")
+                if self.tracing:
+                    self.metrics.observe("tbt_ticks",
+                                         self.tick - seq.last_emit_tick)
+                seq.last_emit_tick = self.tick
+                if decode_ev is not None:
+                    # link before the transition: the kernel served the
+                    # span that was open while this token was in flight
+                    self.trace.link(seq.rid, decode_ev)
+                done = seq.emit(tok)
+                if self.trace is not None:
+                    self.trace.transition(
+                        seq.rid, SpanKind.DECODE, self.tick,
+                        token_index=len(seq.out_tokens) - 1)
+                if done:
+                    self._retire(seq)
                     finished.append(seq)
-                    active.remove(slot)
-        nxt = self._sample(lg)                            # (n_slots,)
-        for slot in active:
-            seq = self._slot_seq[slot]
-            tok = int(nxt[slot])
-            seq.pos += 1
-            self.metrics.inc("decoded_tokens")
-            if self.tracing:
-                self.metrics.observe("tbt_ticks",
-                                     self.tick - seq.last_emit_tick)
-            seq.last_emit_tick = self.tick
-            if decode_ev is not None:
-                # link before the transition: the kernel served the span
-                # that was open while this token was in flight
-                self.trace.link(seq.rid, decode_ev)
-            done = seq.emit(tok)
-            if self.trace is not None:
-                self.trace.transition(seq.rid, SpanKind.DECODE, self.tick,
-                                      token_index=len(seq.out_tokens) - 1)
-            if done:
-                self._retire(seq)
-                finished.append(seq)
-            else:
-                if self.paged and self.cache_mgr.sharing and \
-                        seq.pos % self.page_size == 0:
-                    # a full page of decode-produced tokens just closed:
-                    # publish it so later prompts extending this
-                    # sequence's prompt + output share past the prompt
-                    # (agentic fan-out; CoW handles divergence)
-                    self.cache_mgr.register_decode_page(
-                        slot, seq.written_tokens, chain=seq.prefix_chain)
-                self._tokens[slot, 0] = tok
-                self._pos[slot] = seq.pos
+                else:
+                    if self.paged and self.cache_mgr.sharing and \
+                            seq.pos % self.page_size == 0:
+                        # a full page of decode-produced tokens just
+                        # closed: publish it so later prompts extending
+                        # this sequence's prompt + output share past the
+                        # prompt (agentic fan-out; CoW handles divergence)
+                        self.cache_mgr.register_decode_page(
+                            slot, seq.written_tokens,
+                            chain=seq.prefix_chain)
+                    self._tokens[slot, 0] = tok
+                    self._pos[slot] = seq.pos
         return finished
 
     def step(self) -> List[Sequence]:
@@ -1065,18 +1111,27 @@ class ServeEngine:
 
         Returns the sequences that reached a *terminal* state this tick
         — FINISHED or FAILED; callers distinguish via ``status`` and
-        read the structured error from ``Sequence.error``."""
-        finished = self._reap() if self.guards else []
-        finished += [s for s in self._admit() if s.status.terminal]
-        finished += self._decode_tick()
-        if self.tracing:
-            self.metrics.set_gauge("active_slots", len(self._slot_seq))
-            self.metrics.set_gauge("queue_depth", self.scheduler.n_waiting)
-            if self.paged:
-                self.metrics.set_gauge(
-                    "pool_pages_held",
-                    sum(self.cache_mgr.pages_held().values()))
-        self.tick += 1
+        read the structured error from ``Sequence.error``.
+
+        Each phase of the tick is a ``serve.*`` host span on the
+        profiler's clock (DESIGN.md "Host phases on the profiler's
+        clock")."""
+        with host_span("serve.step", tick=self.tick):
+            finished = []
+            if self.guards:
+                with host_span("serve.reap"):
+                    finished += self._reap()
+            finished += [s for s in self._admit() if s.status.terminal]
+            finished += self._decode_tick()
+            if self.tracing:
+                self.metrics.set_gauge("active_slots", len(self._slot_seq))
+                self.metrics.set_gauge("queue_depth",
+                                       self.scheduler.n_waiting)
+                if self.paged:
+                    self.metrics.set_gauge(
+                        "pool_pages_held",
+                        sum(self.cache_mgr.pages_held().values()))
+            self.tick += 1
         return finished
 
     def run(self, requests: Seq[Request], max_ticks: int = 100_000

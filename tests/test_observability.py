@@ -7,10 +7,14 @@ token — and TTFT falls out as an identity between the span trace and
 the histogram; (2) every metric is recorded in engine ticks, so the
 whole snapshot is equal across xla and pallas-interpret decode; (3)
 ``tracing=False`` changes nothing observable about the served streams;
-(4) the exported trace_event JSON is schema-complete."""
+(4) the exported trace_event JSON is schema-complete; (5) the engine's
+host phases land in the profiler's trace as ``serve.*`` spans, nested
+as DESIGN.md "Host phases on the profiler's clock" lists them."""
 
 import dataclasses
 import json
+import pathlib
+import sys
 
 import jax
 import numpy as np
@@ -27,6 +31,9 @@ from repro.prof.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                 StatsView)
 from repro.prof.trace import RequestTrace, Span, SpanKind, TraceCollector
 from repro.serve.engine import Request, ServeEngine
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "bench"))
+from lib import enginespans  # noqa: E402
 
 KEY = jax.random.PRNGKey(41)
 
@@ -250,7 +257,7 @@ class TestEngineSpans:
                     [(5, 6, 0), (8, 5, 0), (4, 7, 1), (6, 4, 2)])]
         eng.run(reqs)
         assert eng.stats["preemptions"] >= 1
-        kinds = eng.trace.span_kinds()
+        kinds = {s.kind for rt in eng.trace for s in rt.spans}
         assert SpanKind.PREEMPTED in kinds and SpanKind.SWAP in kinds
         for rt in eng.trace:
             assert rt.contiguous(), rt.rid
@@ -365,6 +372,73 @@ class TestEngineSpans:
 
 
 # ------------------------------------------------ export ------------------
+
+# ------------------------------------------- host phases (profiler) -------
+
+# each serve.* span and the span it runs inside
+SERVE_PARENTS = {
+    "serve.step": None,
+    "serve.reap": "serve.step",
+    "serve.admit": "serve.step",
+    "serve.prefill": "serve.admit",
+    "serve.align": "serve.admit",
+    "serve.first_token": "serve.admit",
+    "serve.swap_in": "serve.step",
+    "serve.provision": "serve.step",
+    "serve.table_sync": "serve.step",
+    "serve.decode": "serve.step",
+    "serve.logits_to_host": "serve.step",
+    "serve.guard": "serve.step",
+    "serve.sample": "serve.step",
+    "serve.emit": "serve.step",
+}
+
+
+def _parent(span, spans):
+    """Name of the shortest other span on ``span``'s thread that holds
+    it, or None."""
+    a, b = span["t0"], span["t0"] + span["dur"]
+    holders = [s for s in spans if s is not span
+               and (s["plane"], s["line"]) == (span["plane"], span["line"])
+               and s["t0"] <= a and b <= s["t0"] + s["dur"]
+               and s["dur"] >= span["dur"]]
+    return min(holders, key=lambda s: s["dur"])["name"] if holders \
+        else None
+
+
+def test_host_phases_on_the_profiler_clock(tmp_path):
+    """A paged engine with prefix sharing and one forced preemption,
+    traced by the JAX profiler on the CPU: every ``serve.*`` span
+    appears, under its parent, one ``serve.step`` per tick and one
+    ``serve.admit`` per prefill."""
+    rng = np.random.default_rng(5)
+    pre = [int(t) for t in rng.integers(0, 128, 8)]
+    reqs = [Request(i, pre + [int(t) for t in rng.integers(0, 128, k)],
+                    n, arrival=a)
+            for i, (k, n, a) in enumerate(
+                [(2, 6, 0), (3, 5, 1), (1, 7, 1), (4, 4, 2)])]
+    eng = ServeEngine(HYBRID, params_for(HYBRID), n_slots=3, budget=32,
+                      paged=True, page_size=4, prefill_impl="xla",
+                      fault_plan=FaultPlan(growth_oom={3}))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.stats["shared_tokens"] > 0 and eng.stats["swap_ins"] >= 1
+    spans = [e for e in enginespans.load(str(tmp_path))
+             if e["name"].startswith("serve.")]
+    names = [e["name"] for e in spans]
+    assert set(names) == set(SERVE_PARENTS)
+    for e in spans:
+        assert _parent(e, spans) == SERVE_PARENTS[e["name"]], e
+    assert names.count("serve.step") == eng.tick
+    assert names.count("serve.admit") == eng.stats["prefills"]
+    assert names.count("serve.swap_in") == eng.stats["swap_ins"]
+
 
 class TestExport:
     def test_perfetto_schema_complete(self, tmp_path):
