@@ -19,7 +19,9 @@ memory-bound *and* leaves all but one core idle.  Here a single
 3. **splits the KV axis across the grid** flash-decode style: each of the
    ``S / block_kv`` grid cells produces a partial ``(acc, m, l)`` triple
    and a cheap cross-block combine in XLA merges them — long caches use
-   every core instead of one sequential lane.
+   every core instead of one sequential lane.  The paged pool's kernel
+   instead walks each row's live pages in order, all KV heads per page
+   (``decode_attention.py``).
 
 Ring-buffer invariant (see DESIGN.md): slot ``j`` of a cache of length
 ``S`` holds the K/V of absolute position ``p ≡ j (mod S)``, and the

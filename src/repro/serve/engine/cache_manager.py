@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...kernels.decode_attention.decode_attention import last_live_page
 from ...models import model as M
 from .. import paging as P
 from ..step import cache_slot_extract, cache_slot_insert
@@ -193,6 +194,18 @@ class PagedCacheManager:
         if n_positions >= W:
             return self.n_ptes[kind]
         return math.ceil(max(n_positions, 0) / self.page_size)
+
+    def decode_walk(self, pos: np.ndarray) -> Tuple[int, int]:
+        """Pages the paged decode kernel walks for one step at the
+        positions ``pos`` (one entry per row of the step, ``-1`` for a
+        padded or idle row), summed over the cache kinds, and the pages
+        its grid spans (rows × ``n_ptes``)."""
+        live = budget = 0
+        for kind, n_ptes in self.n_ptes.items():
+            live += int((last_live_page(pos, self.widths[kind],
+                                        self.page_size) + 1).sum())
+            budget += len(pos) * n_ptes
+        return live, budget
 
     def match_prefix(self, prompt, chain=None
                      ) -> Tuple[int, Dict[str, List[int]]]:

@@ -183,7 +183,8 @@ COW_EVENT = "PAGE_COW"
 COUNTER_METRICS = ("decode_steps", "decoded_tokens", "prefills",
                    "preemptions", "swap_ins", "prefill_tokens",
                    "shared_tokens", "prefix_hits", "cow_copies",
-                   "resume_shared_tokens", "failures", "compiles_total")
+                   "resume_shared_tokens", "failures", "compiles_total",
+                   "decode_pages_live", "decode_pages_budget")
 # tick-based latency histograms (unit: engine ticks — deterministic,
 # identical across numeric backends); recorded only while tracing
 HISTOGRAM_METRICS = ("ttft_ticks", "tbt_ticks", "queue_wait_ticks",
@@ -1032,6 +1033,11 @@ class ServeEngine:
                     name=DECODE_EVENT, command_type=DECODE_EVENT)
             self.cache_mgr.update(cache)
             self.metrics.inc("decode_steps")
+            if self.paged:
+                live, budget = self.cache_mgr.decode_walk(
+                    pos if width < self.n_slots else self._pos)
+                self.metrics.inc("decode_pages_live", live)
+                self.metrics.inc("decode_pages_budget", budget)
         with host_span("serve.logits_to_host"):
             if width < self.n_slots:
                 packed_lg = np.asarray(logits[:, 0])          # (W, V)

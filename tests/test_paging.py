@@ -240,6 +240,79 @@ def test_paged_inactive_row_is_nulled():
                                        err_msg=f"{impl} row {b}")
 
 
+WALK = {
+    # Hkv, group, D, ps, n_ptes, window, fills (-1 = inactive), shared
+    # prefix page, cache dtype
+    "depths": (5, 3, 64, 8, 6, None, [3, 17, 30, 46], False, jnp.float32),
+    "fresh-page": (2, 4, 32, 8, 4, None, [8, 16, 0], False, jnp.float32),
+    "budget-end": (1, 1, 32, 8, 4, None, [31, 5], False, jnp.float32),
+    "inactive": (5, 3, 64, 8, 4, None, [10, -1, 25, -1], False,
+                 jnp.float32),
+    "shared-prefix": (8, 4, 128, 8, 4, None, [12, 20], True, jnp.bfloat16),
+    "window-wrapped": (2, 3, 32, 8, 2, 16, [21, 40, 9], False, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK))
+def test_paged_kernel_walks_live_pages(case):
+    """The paged kernel walks each row's live pages only and stores only
+    the row it writes.  Tables hold pages up to each row's last live page
+    (all of them once the ring wraps) and the null page past it, as the
+    serve pool provisions them.  Against the paged oracle: the outputs
+    match, every arena row other than each row's write slot is
+    bit-identical to the input, the written rows hold the new K/V, and
+    the null page's positions stay -1."""
+    Hkv, group, D, ps, n_ptes, window, fills, shared, dt = WALK[case]
+    B, W = len(fills), n_ptes * ps
+    n_pages = 1 + B * n_ptes
+    ks = jax.random.split(KEY, 5)
+    q = jax.random.normal(ks[0], (B, Hkv * group, 1, D), dt)
+    ka = jax.random.normal(ks[1], (n_pages, Hkv, ps, D), dt)
+    va = jax.random.normal(ks[2], (n_pages, Hkv, ps, D), dt)
+    kn = jax.random.normal(ks[3], (B, Hkv, 1, D), dt)
+    vn = jax.random.normal(ks[4], (B, Hkv, 1, D), dt)
+    rng = np.random.default_rng(7)
+    free = list(1 + rng.permutation(n_pages - 1))
+    pt = np.full((B, n_ptes), P.PAGE_NULL, np.int32)
+    pa = np.full((n_pages, ps), -1, np.int32)
+    for b, f in enumerate(fills):
+        if f < 0:
+            continue
+        ring = np.asarray(ring_pos(1, W, f)[0]).reshape(n_ptes, ps)
+        for t in range(min(f, W - 1) // ps + 1):
+            pt[b, t] = pt[0, 0] if shared and b == 1 and t == 0 \
+                else free.pop()
+            pa[pt[b, t]] = ring[t]
+    if shared:
+        assert pt[1, 0] == pt[0, 0] and min(fills) >= ps
+    pos = jnp.asarray(fills, jnp.int32)
+    args = (q, ka, va, jnp.asarray(pa), kn, vn, pos)
+    got = decode_attention(*args, window=window, impl="pallas",
+                           page_table=jnp.asarray(pt))
+    want = decode_attention(*args, window=window, impl="xla",
+                            page_table=jnp.asarray(pt))
+
+    active = [b for b, f in enumerate(fills) if f >= 0]
+    tol = 2e-2 if dt == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got[0], np.float32)[active],
+                               np.asarray(want[0], np.float32)[active],
+                               atol=tol, rtol=tol)
+    widx = np.mod(np.asarray(fills), W)
+    written = np.zeros((n_pages, ps), bool)
+    written[pt[np.arange(B), widx // ps], widx % ps] = True
+    kept = ~written[:, None, :, None]
+    for new_arena, arena, rows in ((got[1], ka, kn), (got[2], va, vn)):
+        new_arena, arena = np.asarray(new_arena), np.asarray(arena)
+        np.testing.assert_array_equal(np.where(kept, new_arena, 0),
+                                      np.where(kept, arena, 0))
+        for b in active:
+            np.testing.assert_array_equal(
+                new_arena[pt[b, widx[b] // ps], :, widx[b] % ps],
+                np.asarray(rows)[b, :, 0])
+    assert np.all(np.asarray(got[3][P.PAGE_NULL]) == -1)
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+
+
 # ------------------------------------------------- pool tree operations ----
 
 TINY = dict(name="tiny-paged", family="dense", num_layers=2, d_model=32,
@@ -400,3 +473,46 @@ def test_paged_engine_page_accounting():
         eng.step()
     eng.finish()
     assert eng.cache_mgr.pages_held()["full"] == 0
+
+
+@pytest.mark.parametrize("cfg,buckets,live,budget", [
+    # one request, prompt 3, 8 new tokens: 7 decode steps at positions
+    # 3..9 over 4-position pages.  Full ring (W 16, 4 pages): the row
+    # walks 1 + 4·2 + 2·3 = 15 pages; at full width the idle row walks
+    # page 0 once a step (+7).  Sliding window (W 8, 2 pages): 1 + 4·2,
+    # then the wrapped ring keeps both pages live (+2·2), idle +7.
+    (DENSE, False, 15 + 7, 7 * 2 * 4),
+    (DENSE, True, 15, 7 * 1 * 4),
+    (HYBRID, False, (15 + 7) + (13 + 7), 7 * 2 * 4 + 7 * 2 * 2),
+], ids=["full-width", "packed", "swa+full"])
+def test_decode_page_counters(cfg, buckets, live, budget):
+    """``decode_pages_live`` / ``decode_pages_budget`` count the pages the
+    paged decode kernel walks and the pages its grid spans, step by
+    step, summed over the cache kinds."""
+    params = M.init_params(cfg, KEY)
+    eng = ServeEngine(cfg, params, n_slots=2, budget=16, paged=True,
+                      page_size=4, buckets=buckets)
+    eng.submit(Request(0, [1, 2, 3], 8))
+    while not eng.done:
+        eng.step()
+    eng.finish()
+    assert eng.stats["decode_steps"] == 7
+    assert eng.stats["decode_pages_live"] == live
+    assert eng.stats["decode_pages_budget"] == budget
+
+
+def test_paged_decode_keeps_kernel_name():
+    """The paged decode step lowers with a computation named
+    ``_decode_attention``: the benchmark finds the kernel's device time
+    by that name (``^%_decode_attention\\b``)."""
+    import re
+    cfg = dataclasses.replace(DENSE, attn_impl="pallas")
+    params = M.init_params(cfg, KEY)
+    eng = ServeEngine(cfg, params, n_slots=2, budget=16, paged=True,
+                      page_size=4)
+    i32 = jnp.int32
+    step = make_decode_step(cfg)
+    hlo = jax.jit(step).lower(
+        params, eng.cache_mgr.cache, jnp.zeros((2, 1), i32),
+        jnp.full((2,), -1, i32)).as_text(dialect="hlo")
+    assert re.search(r"^%?_decode_attention\b\S* \{$", hlo, re.M)

@@ -7,7 +7,8 @@ fail here, on the CPU, before any run on the chip.  Nothing executes.
 
 Shapes are the engine's at SmolLM-360M widths (15 query / 5 KV heads,
 head_dim 64, bf16, 8 decode slots, a 2048-position budget, 128-position
-pages), plus one head_dim-128 GQA width (32 / 8 heads).
+pages), plus one head_dim-128 GQA width (32 / 8 heads); the paged decode
+kernel at both benchmark cells' widths.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers import
@@ -77,11 +78,17 @@ def test_dense_decode_compiles(one_chip, width):
              ((B,), I32), ((B,), I32))
 
 
-def test_paged_decode_compiles(one_chip):
-    B, Hq, Hkv, D, S = DECODE_WIDTHS["smollm-360m"]
+# B, Hq, Hkv, D, n_ptes (128-position pages), pool pages: the benchmark
+# cells' decode widths (64 slots; 16 slots)
+PAGED_WIDTHS = {"smollm-360m": (64, 15, 5, 64, 16, 384),
+                "qwen3-8b": (16, 32, 8, 128, 24, 256)}
+
+
+@pytest.mark.parametrize("width", sorted(PAGED_WIDTHS))
+def test_paged_decode_compiles(one_chip, width):
+    B, Hq, Hkv, D, n_ptes, pool = PAGED_WIDTHS[width]
     ps = 128
-    n_ptes = S // ps
-    n_pages = B * n_ptes + 1              # full provision + the null page
+    n_pages = pool + 1                    # + the null page
     _compile(decode_attention_paged_pallas, one_chip,
              ((B, Hq, 1, D), BF16), ((n_pages, Hkv, ps, D), BF16),
              ((n_pages, Hkv, ps, D), BF16), ((n_pages, ps), I32),
