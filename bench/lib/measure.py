@@ -106,6 +106,21 @@ def decode_step_ms(run: Run) -> Optional[float]:
     return 1e3 * t / n if t else None
 
 
+def decode_pages_share(run: Run) -> Optional[float]:
+    """How full the paged decode walk's budget is over the window, in
+    percent: the engine's ``decode_pages_live`` (each row's pages up to
+    its position) over ``decode_pages_budget`` (the step's rows × the
+    pages a row's table holds), summed over decode steps.  The host works
+    both out from the step's position vector, so the traffic's context
+    lengths and the decode width buckets set the share; it says nothing
+    of what the kernel does with the grid cells past a row's live
+    pages."""
+    budget = run.counters.get("decode_pages_budget", 0)
+    if not budget:
+        return None
+    return 100.0 * run.counters.get("decode_pages_live", 0) / budget
+
+
 def idle_share(run: Run) -> Optional[float]:
     """1 - busy / window, in percent."""
     if run.window is None:

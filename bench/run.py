@@ -153,6 +153,16 @@ def build(cell: spec.Cell, seed: int, annotate):
     return cfg, dims, params, eng
 
 
+def engine_counters(eng) -> dict:
+    """Every counter of the engine's metrics registry, by name.  A run's
+    ``counters`` are their change over the window, so a counter the
+    engine gains reaches the metric readers with no entry here.  Gauges
+    (levels such as ``queue_depth``) and histograms are left out: their
+    change is no count.  The registry names its kinds only in private
+    maps, so the counters' map is read directly."""
+    return {name: c.value for name, c in eng.metrics._counters.items()}
+
+
 def use_cache() -> str:
     """The program's compile cache, keeping every program it compiles."""
     import jax
@@ -201,9 +211,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, dur, **kw: compiles.append(ev)
         if ev.startswith("/jax/core/compile") else None)
-    names = ("decode_steps", "decoded_tokens", "prefills", "prefill_tokens",
-             "shared_tokens", "prefix_hits", "preemptions", "compiles_total")
-    before = {k: eng.stats[k] for k in names}
+    before = engine_counters(eng)
     n_compiles = len(compiles)
     trace_dir = OUT / "trace" / cell.name
     if trace:
@@ -215,7 +223,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     setup_s = time.perf_counter() - T_START
     with annotate(WINDOW_SPAN):
         close = drv.run_window(seconds, open_loop)
-    counters = {k: eng.stats[k] - before[k] for k in names}
+    counters = {k: v - before[k] for k, v in engine_counters(eng).items()}
     in_window = len(compiles) - n_compiles
     drain_end = drv.drain(close) if open_loop else close
     eng.finish()
