@@ -407,8 +407,8 @@ def bench_serve_throughput():
             # lockstep: the whole batch decodes until its slowest member
             # is done; finished members keep occupying their slots
             for _ in range(max(r.max_new_tokens for r in group) - 1):
-                logits, cache = decode(params, mgr.cache,
-                                       jnp.asarray(toks), jnp.asarray(pos))
+                logits, cache, _ = decode(params, mgr.cache,
+                                          jnp.asarray(toks), jnp.asarray(pos))
                 mgr.update(cache)
                 steps += 1
                 nxt = np.argmax(np.asarray(logits[:, 0]), -1)

@@ -81,9 +81,9 @@ def main() -> int:
     generated = [tok]
     for i in range(args.tokens - 1):
         pos = jnp.int32(args.prompt_len + i)
-        logits, cache = q_decode.enqueue(decode, params, cache, tok, pos,
-                                         name=DECODE_EVENT,
-                                         command_type=DECODE_EVENT)
+        logits, cache, _ = q_decode.enqueue(decode, params, cache, tok,
+                                            pos, name=DECODE_EVENT,
+                                            command_type=DECODE_EVENT)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         generated.append(tok)
     q_decode.finish()

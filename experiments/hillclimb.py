@@ -43,15 +43,12 @@ RUNS = [
     # ---- mixtral-8x7b train_4k ---------------------------------------------
     ("mixtral-8x7b", "train_4k", "hc-base", {},
      "baseline MoE: EP-fallback TP + FSDP"),
-    ("mixtral-8x7b", "train_4k", "hc-cap1", {"capacity_factor": 1.0},
-     "capacity 1.0: expert GEMM flops and dispatch traffic down 20%"),
     ("mixtral-8x7b", "train_4k", "hc-qb4", {"attn_qblocks": 4},
      "causal chunk skip on the SWA layers"),
     ("mixtral-8x7b", "train_4k", "hc-dp", {"rules": "dp", "microbatches": 2},
      "pure DP/FSDP: experts local, no dispatch resharding collectives"),
     ("mixtral-8x7b", "train_4k", "hc-best",
-     {"rules": "dp", "microbatches": 2, "attn_qblocks": 4,
-      "capacity_factor": 1.0},
+     {"rules": "dp", "microbatches": 2, "attn_qblocks": 4},
      "combined"),
     # ---- smollm-360m train_4k ----------------------------------------------
     ("smollm-360m", "train_4k", "hc-base", {},

@@ -23,6 +23,7 @@ ARCHS: List[str] = [
     "llama4_maverick_400b_a17b",
     "llama32_vision_11b",
     "recurrentgemma_9b",
+    "mellum2_12b",
 ]
 
 # Canonical external ids (assignment sheet) → module names
@@ -37,6 +38,7 @@ ALIASES: Dict[str, str] = {
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
     "llama-3.2-vision-11b": "llama32_vision_11b",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "mellum2-12b": "mellum2_12b",
 }
 
 SHAPES: Dict[str, Dict] = {

@@ -19,7 +19,7 @@ def config() -> ModelConfig:
                  ("chunked", "dense"), ("global_nope", "moe")),
         act="silu", glu=True, rope_theta=5e5,
         chunk=8192,
-        n_experts=128, top_k=1, capacity_factor=1.25, shared_expert=True,
+        n_experts=128, top_k=1, shared_expert=True,
         sub_quadratic=False,
     )
 
@@ -32,6 +32,6 @@ def smoke_config() -> ModelConfig:
         pattern=(("chunked", "dense"), ("chunked", "moe"),
                  ("chunked", "dense"), ("global_nope", "moe")),
         act="silu", glu=True, chunk=16,
-        n_experts=4, top_k=1, capacity_factor=1.5, shared_expert=True,
+        n_experts=4, top_k=1, shared_expert=True,
         sub_quadratic=False, dtype="float32",
     )

@@ -15,7 +15,7 @@ def config() -> ModelConfig:
         pattern=(("swa", "moe"),),
         act="silu", glu=True, rope_theta=1e6,
         window=4096,
-        n_experts=8, top_k=2, capacity_factor=1.25,
+        n_experts=8, top_k=2,
         sub_quadratic=True,
     )
 
@@ -27,6 +27,6 @@ def smoke_config() -> ModelConfig:
         head_dim=16, d_ff=128, vocab=256,
         pattern=(("swa", "moe"),),
         act="silu", glu=True, window=16,
-        n_experts=4, top_k=2, capacity_factor=1.5,
+        n_experts=4, top_k=2,
         sub_quadratic=True, dtype="float32",
     )

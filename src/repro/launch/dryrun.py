@@ -72,7 +72,7 @@ def probe_block(cfg, ctx, context, gi: int, kind: str, B: int, S: int,
         def block_loss(x, lp, ctxe=None):
             y, _, aux = M.apply_superblock(acfg, pattern, x, lp, None,
                                            positions, ctxe, False)
-            return y.astype(jnp.float32).sum() + aux
+            return y.astype(jnp.float32).sum() + aux.loss
         inner = M.remat_wrap(acfg, block_loss)
 
         if ctxe is None:

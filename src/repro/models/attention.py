@@ -110,8 +110,8 @@ def self_attention(p, x, cfg, kind: str, positions,
         k = _qk_norm(k, p["k_norm"], cfg.rms_eps)
     use_rope = kind != "global_nope"
     if use_rope:
-        q = L.rope(q, positions, cfg.rope_theta)
-        k = L.rope(k, positions, cfg.rope_theta)
+        q = L.rope(q, positions, cfg.rope_for(kind))
+        k = L.rope(k, positions, cfg.rope_for(kind))
     q = shard_activation(q, ("batch", "heads", None, None))
     k = shard_activation(k, ("batch", "kv_heads", None, None))
 

@@ -69,15 +69,59 @@ def rmsnorm_tpl(d: int, dtype: str) -> ParamTpl:
 
 # ---------------------------------------------------------------- rope -------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 1e4) -> jax.Array:
-    """x: (B, H, T, D_head); positions: (B, T) or (T,)."""
-    d = x.shape[-1]
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """RoPE of one kind of attention layer: base ``theta`` and, with
+    ``factor > 1``, YaRN scaling (Peng et al. 2023) as Hugging Face's
+    ``_compute_yarn_parameters`` computes it: the inverse frequencies
+    blend ``inv_freq / factor`` (interpolation) with ``inv_freq``
+    (extrapolation) along a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations over ``original_len``
+    positions, and cos and sin are scaled by ``attention_factor``."""
+    theta: float = 1e4
+    factor: float = 1.0
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def rope_tables(spec: Rope, d: int) -> Tuple[jax.Array, float]:
+    """``(inverse frequencies (d/2,) f32, cos/sin scale)`` of ``spec`` for
+    heads of ``d``; plain RoPE's frequencies are ``theta ** (-i / (d/2))``
+    exactly as they have always been computed here."""
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = spec.theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if spec.factor == 1.0:
+        return freqs, spec.attention_factor
+
+    def corr_dim(rotations):
+        return (d * math.log(spec.original_len / (rotations * 2 * math.pi))
+                ) / (2 * math.log(spec.theta))
+
+    low = max(math.floor(corr_dim(spec.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(spec.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    freqs = (freqs / spec.factor) * (1.0 - extrapolate) \
+        + freqs * extrapolate
+    return freqs, spec.attention_factor
+
+
+def rope(x: jax.Array, positions: jax.Array, spec: Rope = Rope()
+         ) -> jax.Array:
+    """x: (B, H, T, D_head); positions: (B, T) or (T,)."""
+    half = x.shape[-1] // 2
+    freqs, scale = rope_tables(spec, x.shape[-1])
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # B1TH
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
@@ -191,7 +235,8 @@ def linear_tpl(d_in: int, d_out: int, logical: Tuple, dtype: str,
 
 
 __all__ = [
-    "ParamTpl", "init_tree", "stack_tpl", "rmsnorm", "rmsnorm_tpl", "rope",
+    "ParamTpl", "init_tree", "stack_tpl", "rmsnorm", "rmsnorm_tpl", "Rope",
+    "rope_tables", "rope",
     "mlp", "mlp_tpl", "embed", "embed_tpl", "unembed", "cross_entropy",
     "chunked_cross_entropy", "linear_tpl",
 ]

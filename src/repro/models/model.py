@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +37,15 @@ from . import ssm as S
 from .layers import ParamTpl
 
 Pattern = Tuple[Tuple[str, str], ...]
+
+
+class Aux(NamedTuple):
+    """What a forward pass reports beside the hidden states: the
+    load-balancing loss summed over the expert layers, and per expert
+    layer (in model order) the int32 counts ``[pairs, experts hit]`` of
+    its real tokens' routing (``(0, 2)`` for a model without experts)."""
+    loss: jax.Array
+    moe: jax.Array
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +64,9 @@ class ModelConfig:
     glu: bool = True
     qk_norm: bool = False
     rope_theta: float = 1e4
+    # RoPE per attention kind, ((kind, Rope), ...): a kind not listed
+    # takes plain RoPE at ``rope_theta``
+    rope_kinds: Tuple[Tuple[str, L.Rope], ...] = ()
     rms_eps: float = 1e-6
     norm_plus_one: bool = False
     embed_scale: bool = False
@@ -65,7 +77,7 @@ class ModelConfig:
     # MoE
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True     # renormalise the top-k gates to sum 1
     shared_expert: bool = False
     aux_loss_weight: float = 0.01
     # SSM (mamba2)
@@ -84,7 +96,10 @@ class ModelConfig:
     vis_tokens: int = 0
     # numerics / impl
     dtype: str = "bfloat16"
-    attn_impl: str = "xla"          # xla | pallas | auto (autotuned)
+    # xla | pallas | auto (autotuned): every Pallas kernel of the model,
+    # attention and the expert layers' grouped matmuls
+    # (``kernels/grouped_matmul``; ``xla`` runs ``lax.ragged_dot``) alike
+    attn_impl: str = "xla"
     attn_chunk: int = 256           # KV-chunk of the streaming softmax
     attn_qblocks: int = 1           # >1: static causal chunk skipping
     norm_impl: str = "xla"
@@ -112,6 +127,9 @@ class ModelConfig:
     def has_cross(self) -> bool:
         return any(m in ("self_cross", "cross")
                    for m, _ in self.pattern)
+
+    def rope_for(self, kind: str) -> L.Rope:
+        return dict(self.rope_kinds).get(kind, L.Rope(self.rope_theta))
 
     def cache_len(self, mixer: str, seq_len: int) -> int:
         if mixer in ("swa", "local"):
@@ -209,13 +227,18 @@ def _norm(cfg, w, x):
 
 
 def _apply_layer(cfg: ModelConfig, mixer: str, ffn: str, p, x, positions,
-                 ctx, cache, rolling: bool):
-    """One transformer-ish layer. Returns (x, new_cache, aux).
+                 ctx, cache, rolling: bool, rep=None):
+    """One transformer-ish layer. Returns (x, new_cache, aux, counts):
+    ``counts`` the expert layer's int32 ``[pairs, experts hit]``, None
+    for a layer without experts.  With ``rep`` the expert stacks of
+    ``p`` are whole (see :func:`_split_expert_stacks`) and ``rep`` is
+    this layer's repeat in them.
 
     ``ctx`` is the raw encoder/vision embedding sequence (B, S_ctx, D);
     cross layers project their own K/V from it.
     """
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     h = _norm(cfg, p["ln1"], x)
     new_cache = cache
     if mixer == "ssm":
@@ -242,26 +265,35 @@ def _apply_layer(cfg: ModelConfig, mixer: str, ffn: str, p, x, positions,
     if ffn != "none":
         h = _norm(cfg, p["ln2"], x)
         if ffn == "moe":
-            out, aux = M.moe_ffn(p["ffn"], h, cfg)
+            # padding positions (-1: bucket tails, idle decode rows) are
+            # routed nowhere
+            valid = jnp.broadcast_to(positions >= 0, x.shape[:2])
+            out, aux, counts = M.moe_ffn(p["ffn"], h, cfg, valid,
+                                         layer=rep)
         else:
             out = L.mlp(p["ffn"], h, cfg.act, cfg.glu)
         x = x + out
     x = shard_activation(x, ("batch", "seq_ctx", "embed"))
-    return x, new_cache, aux
+    return x, new_cache, aux, counts
 
 
 def apply_superblock(cfg: ModelConfig, pattern: Pattern, x, layer_params,
-                     layer_caches, positions, ctx, rolling: bool):
-    """One superblock (one scan iteration): the unit the dry-run probes."""
+                     layer_caches, positions, ctx, rolling: bool, rep=None):
+    """One superblock (one scan iteration): the unit the dry-run probes.
+    Returns ``(x, caches, Aux)``, the expert counts of the pattern's
+    expert layers in pattern order.  ``rep``: see :func:`_apply_layer`."""
     aux_tot = jnp.zeros((), jnp.float32)
-    new_caches = []
+    new_caches, moe = [], []
     for i, (mixer, ffn) in enumerate(pattern):
         c = layer_caches[i] if layer_caches is not None else None
-        x, nc, aux = _apply_layer(cfg, mixer, ffn, layer_params[i], x,
-                                  positions, ctx, c, rolling)
+        x, nc, aux, counts = _apply_layer(cfg, mixer, ffn, layer_params[i],
+                                          x, positions, ctx, c, rolling, rep)
         new_caches.append(nc)
         aux_tot = aux_tot + aux
-    return x, tuple(new_caches), aux_tot
+        if counts is not None:
+            moe.append(counts)
+    return x, tuple(new_caches), Aux(
+        aux_tot, jnp.stack(moe) if moe else jnp.zeros((0, 2), jnp.int32))
 
 
 def remat_wrap(cfg: ModelConfig, fn):
@@ -274,6 +306,33 @@ def remat_wrap(cfg: ModelConfig, fn):
     return fn
 
 
+def _split_expert_stacks(pattern: Pattern, layers):
+    """A group's stacked layer params without the expert stacks
+    (``moe.EXPERT_STACKS``) of its expert layers, and those stacks whole,
+    per pattern position (``{}`` for a layer without experts).  The
+    stacks are handed to every repeat whole, with the repeat's index:
+    the grouped expert kernel reads its layer's experts out of the
+    stack, where a slice per repeat would copy every expert matrix of
+    the layer before the kernel reads the few a decode step routes to."""
+    rest, stacks = [], []
+    for (_, ffn), lp in zip(pattern, layers):
+        whole = {k: v for k, v in lp["ffn"].items()
+                 if k in M.EXPERT_STACKS} if ffn == "moe" else {}
+        if whole:
+            lp = dict(lp, ffn={k: v for k, v in lp["ffn"].items()
+                               if k not in whole})
+        rest.append(lp)
+        stacks.append(whole)
+    return tuple(rest), tuple(stacks)
+
+
+def _join_expert_stacks(layers, stacks):
+    """The inverse of :func:`_split_expert_stacks` on one repeat's
+    params: each expert layer's ``ffn`` gets its whole stacks back."""
+    return tuple(dict(lp, ffn={**lp["ffn"], **s}) if s else lp
+                 for lp, s in zip(layers, stacks))
+
+
 def _group_forward(cfg: ModelConfig, pattern: Pattern, count: int, gp,
                    x, positions, ctx, caches, rolling: bool):
     """Scan a superblock over ``count`` repeats.
@@ -282,33 +341,39 @@ def _group_forward(cfg: ModelConfig, pattern: Pattern, count: int, gp,
     pytrees with leading dim = count.
     """
     superblock = remat_wrap(
-        cfg, lambda x, lp, lc: apply_superblock(
-            cfg, pattern, x, lp, lc, positions, ctx, rolling))
+        cfg, lambda x, lp, lc, rep: apply_superblock(
+            cfg, pattern, x, lp, lc, positions, ctx, rolling, rep))
+    layers, stacks = _split_expert_stacks(pattern, gp["layers"])
 
     emit = caches is not None or cfg.collect_kv
     if not cfg.scan_layers or count == 1:
         aux_tot = jnp.zeros((), jnp.float32)
-        new_caches_all = []
+        new_caches_all, moe = [], []
         for j in range(count):
-            lp = jax.tree.map(lambda a: a[j], gp["layers"])
+            lp = jax.tree.map(lambda a: a[j], layers)
             lc = None if caches is None else \
                 jax.tree.map(lambda a: a[j], caches)
-            x, ncs, aux = superblock(x, lp, lc)
+            x, ncs, aux = superblock(x, _join_expert_stacks(lp, stacks),
+                                     lc, jnp.int32(j))
             new_caches_all.append(ncs)
-            aux_tot = aux_tot + aux
+            aux_tot = aux_tot + aux.loss
+            moe.append(aux.moe)
         new_stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs), *new_caches_all) if emit else None
-        return x, new_stacked, aux_tot
+        return x, new_stacked, Aux(aux_tot, jnp.concatenate(moe))
 
     def body(carry, xs):
         x, aux_tot = carry
-        lp, lc = xs
-        x, ncs, aux = superblock(x, lp, lc)
-        return (x, aux_tot + aux), ncs
+        lp, lc, rep = xs
+        x, ncs, aux = superblock(x, _join_expert_stacks(lp, stacks), lc,
+                                 rep)
+        return (x, aux_tot + aux.loss), (ncs, aux.moe)
 
-    (x, aux_tot), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (gp["layers"], caches))
-    return x, new_caches, aux_tot
+    (x, aux_tot), (new_caches, moe) = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32)),
+        (layers, caches, jnp.arange(count, dtype=jnp.int32)))
+    # (count, expert layers of the pattern, 2) → model layer order
+    return x, new_caches, Aux(aux_tot, moe.reshape(-1, 2))
 
 
 def forward(cfg: ModelConfig, params, tokens, *,
@@ -316,8 +381,8 @@ def forward(cfg: ModelConfig, params, tokens, *,
             cache: Optional[Dict] = None,
             pos0: Optional[jax.Array] = None,
             positions: Optional[jax.Array] = None
-            ) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
-    """Returns (hidden (B,T,D), new_cache, aux_loss).
+            ) -> Tuple[jax.Array, Optional[Dict], Aux]:
+    """Returns (hidden (B,T,D), new_cache, :class:`Aux`).
 
     Training/prefill: cache=None, positions = arange(T).
     Decode: cache given, tokens (B,1), pos0 the absolute position — a
@@ -369,16 +434,18 @@ def forward(cfg: ModelConfig, params, tokens, *,
         if ctx is not None:
             new_cache["ctx_enc"] = ctx
     aux_tot = jnp.zeros((), jnp.float32)
+    moe = []
     for gi, (pattern, count) in enumerate(cfg.groups):
         gp = params["groups"][gi]
         gc = None if cache is None else cache["groups"][gi]
         x, ngc, aux = _group_forward(cfg, pattern, count, gp, x, positions,
                                      ctx, gc, rolling=cache is not None)
-        aux_tot = aux_tot + aux
+        aux_tot = aux_tot + aux.loss
+        moe.append(aux.moe)
         if new_cache is not None:
             new_cache["groups"][gi] = ngc
     x = _norm(cfg, params["ln_f"], x)
-    return x, new_cache, aux_tot
+    return x, new_cache, Aux(aux_tot, jnp.concatenate(moe))
 
 
 def encode(cfg: ModelConfig, params, frames: jax.Array) -> jax.Array:
@@ -415,7 +482,7 @@ def loss_fn(cfg: ModelConfig, params, tokens, labels,
     else:
         logits = L.unembed(emb, hidden, cfg.logits_softcap)
         ce = L.cross_entropy(logits, labels)
-    return ce + cfg.aux_loss_weight * aux
+    return ce + cfg.aux_loss_weight * aux.loss
 
 
 # ================================================================== cache ===
@@ -483,14 +550,17 @@ def cache_init(cfg: ModelConfig, batch: int, seq_len: int,
 
 def decode_step(cfg: ModelConfig, params, cache: Dict, token: jax.Array,
                 pos: jax.Array, ctx_embed: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, Dict]:
+                ) -> Tuple[jax.Array, Dict, jax.Array]:
     """One-token decode: token (B,1) int32; pos scalar int32 (lockstep) or
-    (B,) int32 per-sequence absolute positions (-1 = inactive slot)."""
-    hidden, new_cache, _ = forward(cfg, params, token, ctx_embed=ctx_embed,
-                                   cache=cache, pos0=pos)
-    return logits_fn(cfg, params, hidden), new_cache
+    (B,) int32 per-sequence absolute positions (-1 = inactive slot).
+    Returns ``(logits, new_cache, moe)``, ``moe`` the expert layers'
+    routing counts (``Aux.moe``)."""
+    hidden, new_cache, aux = forward(cfg, params, token,
+                                     ctx_embed=ctx_embed, cache=cache,
+                                     pos0=pos)
+    return logits_fn(cfg, params, hidden), new_cache, aux.moe
 
 
-__all__ = ["ModelConfig", "param_template", "init_params", "param_count",
-           "forward", "encode", "loss_fn", "logits_fn", "cache_init",
-           "cache_layout", "KV_KINDS", "decode_step"]
+__all__ = ["ModelConfig", "Aux", "param_template", "init_params",
+           "param_count", "forward", "encode", "loss_fn", "logits_fn",
+           "cache_init", "cache_layout", "KV_KINDS", "decode_step"]

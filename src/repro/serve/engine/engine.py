@@ -146,8 +146,9 @@ per-request and would need slot packing of ``ctx_enc`` too.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence as Seq
+from typing import Callable, Dict, List, Optional, Sequence as Seq, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -184,7 +185,9 @@ COUNTER_METRICS = ("decode_steps", "decoded_tokens", "prefills",
                    "preemptions", "swap_ins", "prefill_tokens",
                    "shared_tokens", "prefix_hits", "cow_copies",
                    "resume_shared_tokens", "failures", "compiles_total",
-                   "decode_pages_live", "decode_pages_budget")
+                   "decode_pages_live", "decode_pages_budget",
+                   "moe_pairs.prefill", "moe_pairs.decode",
+                   "moe_experts_hit.prefill", "moe_experts_hit.decode")
 # tick-based latency histograms (unit: engine ticks — deterministic,
 # identical across numeric backends); recorded only while tracing
 HISTOGRAM_METRICS = ("ttft_ticks", "tbt_ticks", "queue_wait_ticks",
@@ -436,9 +439,9 @@ class ServeEngine:
                               jnp.full((W,), -1, jnp.int32),
                               jnp.asarray(pad))
         for Lb in reg.lengths:
-            _, one = reg.prefill(Lb)(self.params,
-                                     jnp.zeros((1, Lb), jnp.int32),
-                                     jnp.int32(1))
+            _, one, _ = reg.prefill(Lb)(self.params,
+                                        jnp.zeros((1, Lb), jnp.int32),
+                                        jnp.int32(1))
             reg.align(Lb)(one, jnp.int32(1), jnp.int32(0))
         self._n_compile_seen = len(self._registry.events)
 
@@ -620,7 +623,7 @@ class ServeEngine:
                 tail_len = reg.len_bucket(L - shared_toks)
                 tail = np.zeros((1, tail_len), np.int32)
                 tail[0, :L - shared_toks] = tokens[shared_toks:]
-                logits, cache = self.q_admit.enqueue(
+                logits, cache, moe = self.q_admit.enqueue(
                     reg.prefill_ext(prefix_pad, tail_len), self.params,
                     jnp.asarray(tail), prefix, jnp.int32(shared_toks),
                     jnp.int32(L),
@@ -632,7 +635,7 @@ class ServeEngine:
                 prefix_pad = 0
                 prompt = np.zeros((1, ring_len), np.int32)
                 prompt[0, :L] = tokens
-                logits, cache = self.q_admit.enqueue(
+                logits, cache, moe = self.q_admit.enqueue(
                     reg.prefill(ring_len), self.params, jnp.asarray(prompt),
                     jnp.int32(L),
                     name=PREFILL_EVENT, command_type=PREFILL_EVENT)
@@ -684,7 +687,8 @@ class ServeEngine:
         seq.pos = seq.prompt_len
         with host_span("serve.first_token"):
             # first output token comes from the prefill logits
-            lg = np.asarray(logits[:, -1])
+            lg, counts = self._to_host(logits[:, -1], moe)
+            self._count_moe("prefill", counts)
             if self.guards and not np.isfinite(lg).all():
                 raise ReproError(
                     Code.NUMERIC_FAULT,
@@ -993,6 +997,26 @@ class ServeEngine:
         flush()
         return failed
 
+    @staticmethod
+    def _to_host(logits, moe) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """A step's logits on the host, with the expert layers' routing
+        counts beside them in the same transfer (None for a model
+        without experts, whose counts are empty)."""
+        if not moe.shape[0]:
+            return np.asarray(logits), None
+        lg, counts = jax.device_get((logits, moe))
+        return np.asarray(lg), counts
+
+    def _count_moe(self, kind: str, counts: Optional[np.ndarray]) -> None:
+        """Add a step's ``[pairs, experts hit]`` summed over the expert
+        layers to the ``moe_pairs.<kind>`` and ``moe_experts_hit.<kind>``
+        counters."""
+        if counts is None:
+            return
+        pairs, hit = (int(v) for v in counts.sum(axis=0))
+        self.metrics.inc(f"moe_pairs.{kind}", pairs)
+        self.metrics.inc(f"moe_experts_hit.{kind}", hit)
+
     def _decode_tick(self) -> List[Sequence]:
         finished: List[Sequence] = []
         if self.paged:
@@ -1020,13 +1044,13 @@ class ServeEngine:
                 tok[:na] = self._tokens[active]
                 pos = np.full((width,), -1, np.int32)
                 pos[:na] = self._pos[active]
-                logits, cache = self.q_decode.enqueue(
+                logits, cache, moe = self.q_decode.enqueue(
                     self._registry.decode(width), self.params,
                     self.cache_mgr.cache, jnp.asarray(tok),
                     jnp.asarray(pos), jnp.asarray(rows),
                     name=DECODE_EVENT, command_type=DECODE_EVENT)
             else:
-                logits, cache = self.q_decode.enqueue(
+                logits, cache, moe = self.q_decode.enqueue(
                     self._registry.decode_full(), self.params,
                     self.cache_mgr.cache,
                     jnp.asarray(self._tokens), jnp.asarray(self._pos),
@@ -1040,14 +1064,15 @@ class ServeEngine:
                 self.metrics.inc("decode_pages_budget", budget)
         with host_span("serve.logits_to_host"):
             if width < self.n_slots:
-                packed_lg = np.asarray(logits[:, 0])          # (W, V)
+                packed_lg, counts = self._to_host(logits[:, 0], moe)
                 # expand to slot-indexed logits so sampling, fault
                 # injection and the quarantine stay on logical slots
                 lg = np.zeros((self.n_slots,) + packed_lg.shape[1:],
                               packed_lg.dtype)
                 lg[active] = packed_lg[:na]
             else:
-                lg = np.asarray(logits[:, 0])             # (n_slots, V)
+                lg, counts = self._to_host(logits[:, 0], moe)
+            self._count_moe("decode", counts)
         decode_ev = None
         if self.trace is not None:
             decode_ev = self.q_decode.last_event()

@@ -35,7 +35,10 @@ padding — so a trace with thousands of distinct prompt lengths compiles
 at most ``len(ladder)`` prefill programs.  The registry wraps each step
 to detect actual traces (jit cache-size delta), recording a
 ``TRACE_COMPILE`` profiler event and a per-kind compile count that the
-engine surfaces as ``stats()["compiles"]``.
+engine surfaces as ``stats()["compiles"]``.  The registry's prefill and
+decode steps return ``(logits, cache, moe)``: ``moe`` is the expert
+layers' ``(layers, 2)`` int32 routing counts (``model.Aux.moe``), which
+the engine brings to the host with the logits.
 """
 
 from __future__ import annotations
@@ -123,8 +126,10 @@ def make_prefill_step(cfg: M.ModelConfig, ctx: Optional[ShardCtx] = None):
 
 
 def make_decode_step(cfg: M.ModelConfig, ctx: Optional[ShardCtx] = None):
-    """Jitted decode step; cached on ``(cfg, ctx)`` (see
-    :func:`make_prefill_step`)."""
+    """Jitted decode step, ``(params, cache, token, pos) → (logits, cache,
+    moe)`` (``model.decode_step``); cached on ``(cfg, ctx)`` (see
+    :func:`make_prefill_step`).  The serve engine's full-width decode is
+    this same program."""
     return _cached_decode(cfg, ctx)
 
 
@@ -286,14 +291,14 @@ def _build_prefill_bucket_step(cfg: M.ModelConfig, ctx: Optional[ShardCtx],
         with use_ctx(ctx):
             ar = jnp.arange(bucket_len, dtype=jnp.int32)
             positions = jnp.where(ar < true_len, ar, -1)
-            hidden, cache, _ = M.forward(pcfg, params, tokens,
-                                         ctx_embed=ctx_embed,
-                                         positions=positions)
+            hidden, cache, aux = M.forward(pcfg, params, tokens,
+                                           ctx_embed=ctx_embed,
+                                           positions=positions)
             # first output token falls out of the *last real* position
             last = jax.lax.dynamic_slice_in_dim(hidden, true_len - 1, 1,
                                                 axis=1)
             logits = M.logits_fn(pcfg, params, last)
-        return logits, cache
+        return logits, cache, aux.moe
 
     return jax.jit(prefill_bucket)
 
@@ -322,13 +327,13 @@ def _build_prefill_ext_bucket_step(cfg: M.ModelConfig,
             ar = jnp.arange(tail_len, dtype=jnp.int32)
             positions = jnp.where(true_prefix + ar < true_len,
                                   true_prefix + ar, -1)
-            hidden, cache, _ = M.forward(pcfg, params, tokens,
-                                         cache=prefix_cache,
-                                         positions=positions)
+            hidden, cache, aux = M.forward(pcfg, params, tokens,
+                                           cache=prefix_cache,
+                                           positions=positions)
             last = jax.lax.dynamic_slice_in_dim(
                 hidden, true_len - true_prefix - 1, 1, axis=1)
             logits = M.logits_fn(pcfg, params, last)
-        return logits, cache
+        return logits, cache, aux.moe
 
     return jax.jit(prefill_ext_bucket)
 
@@ -409,10 +414,10 @@ def _build_decode_packed_step(cfg: M.ModelConfig, ctx: Optional[ShardCtx]):
     def decode_packed(params, cache, token, pos, rows):
         with use_ctx(ctx):
             small = gather_batch_rows(cfg, cache, rows)
-            logits, new_small = M.decode_step(cfg, params, small, token,
-                                              pos)
+            logits, new_small, moe = M.decode_step(
+                cfg, params, small, token, pos)
             new_cache = scatter_batch_rows(cfg, cache, new_small, rows)
-        return logits, new_cache
+        return logits, new_cache, moe
 
     return jax.jit(decode_packed)
 
@@ -547,19 +552,13 @@ class BucketRegistry:
     def decode_full(self):
         """The classic full-width decode step (no gather/scatter), used
         when the covering bucket is ``n_slots`` itself."""
-        return self._get("decode", (self.n_slots,), _build_decode_step_of,
+        return self._get("decode", (self.n_slots,), _cached_decode,
                          self.cfg, self.ctx)
 
     def align(self, ring_len: int, prefix_pad: int = 0):
         return self._get("align", (ring_len, prefix_pad),
                          _cached_align_bucket, self.cfg, ring_len,
                          self.budget, self.page_size, prefix_pad)
-
-
-def _build_decode_step_of(cfg: M.ModelConfig, ctx: Optional[ShardCtx]):
-    # indirection so the registry shares the legacy decode jit (and its
-    # compiled programs) with make_decode_step callers
-    return _cached_decode(cfg, ctx)
 
 
 def _slot_index(leaf_ndim: int, slot, axis: int):

@@ -60,7 +60,7 @@ def test_decode_step_shapes(arch):
                             if cfg.encoder_layers else
                             ce.astype(jnp.float32))
     tok = jax.random.randint(KEY, (B, 1), 0, cfg.vocab)
-    logits, cache2 = M.decode_step(cfg, params, cache, tok, jnp.int32(7))
+    logits, cache2, _ = M.decode_step(cfg, params, cache, tok, jnp.int32(7))
     assert logits.shape == (B, 1, cfg.vocab)
     assert bool(jnp.isfinite(logits).all()), arch
     # cache structure preserved
@@ -85,6 +85,7 @@ def test_full_config_metadata(arch):
         "llama4_maverick_400b_a17b": (48, 5120, 40, 8, 8192, 202048),
         "llama32_vision_11b": (40, 4096, 32, 8, 14336, 128256),
         "recurrentgemma_9b": (38, 4096, 16, 1, 12288, 256000),
+        "mellum2_12b": (28, 2304, 32, 4, 896, 98304),
     }[arch]
     L, d, h, kv, ff, vocab = sheet
     assert cfg.num_layers == L and cfg.d_model == d
@@ -96,7 +97,8 @@ def test_full_config_metadata(arch):
 
 
 def test_moe_active_params_below_total():
-    for arch in ("mixtral_8x7b", "llama4_maverick_400b_a17b"):
+    for arch in ("mixtral_8x7b", "llama4_maverick_400b_a17b",
+                 "mellum2_12b"):
         t, a = M.param_count(get_config(arch))
         assert a < t
 

@@ -47,8 +47,8 @@ def test_decode_matches_teacher_forcing(arch):
 
     decode = make_decode_step(cfg)
     for t in range(Tp, T):
-        logits_d, cache = decode(params, cache, toks[:, t:t + 1],
-                                 jnp.int32(t))
+        logits_d, cache, _ = decode(params, cache, toks[:, t:t + 1],
+                                    jnp.int32(t))
         np.testing.assert_allclose(
             np.asarray(logits_d[:, 0]), np.asarray(tf_logits[:, t]),
             atol=2e-2, rtol=2e-2,
@@ -98,8 +98,8 @@ def test_align_target_len_padding_masked(arch, attn_impl):
     decode = make_decode_step(cfg)
     for t in range(Tp, T):
         tok = toks[:, t:t + 1]
-        l_snug, snug = decode(params, snug, tok, jnp.int32(t))
-        l_fat, fat = decode(params, fat, tok, jnp.int32(t))
+        l_snug, snug, _ = decode(params, snug, tok, jnp.int32(t))
+        l_fat, fat, _ = decode(params, fat, tok, jnp.int32(t))
         np.testing.assert_allclose(
             np.asarray(l_snug), np.asarray(l_fat), atol=1e-4, rtol=1e-4,
             err_msg=f"{arch}/{attn_impl}: padding leaks at position {t}")
@@ -125,9 +125,9 @@ def test_per_sequence_pos_matches_scalar(arch):
         decode = make_decode_step(cfg)
         for t in range(Tp, T):
             tok = toks[:, t:t + 1]
-            l_s, cache = decode(params, cache, tok, jnp.int32(t))
-            l_v, cache_v = decode(params, cache_v, tok,
-                                  jnp.full((B,), t, jnp.int32))
+            l_s, cache, _ = decode(params, cache, tok, jnp.int32(t))
+            l_v, cache_v, _ = decode(params, cache_v, tok,
+                                     jnp.full((B,), t, jnp.int32))
             np.testing.assert_allclose(
                 np.asarray(l_s), np.asarray(l_v), atol=1e-4, rtol=1e-4,
                 err_msg=f"{arch}/{attn_impl}: vector pos diverges at {t}")
@@ -153,8 +153,8 @@ def test_pallas_decode_matches_teacher_forcing(arch):
 
     decode = make_decode_step(dataclasses.replace(cfg, attn_impl="pallas"))
     for t in range(Tp, T):
-        logits_d, cache = decode(params, cache, toks[:, t:t + 1],
-                                 jnp.int32(t))
+        logits_d, cache, _ = decode(params, cache, toks[:, t:t + 1],
+                                    jnp.int32(t))
         np.testing.assert_allclose(
             np.asarray(logits_d[:, 0]), np.asarray(tf_logits[:, t]),
             atol=2e-2, rtol=2e-2,

@@ -63,9 +63,9 @@ def lockstep_single(cfg, params, prompt, max_new, budget):
     out = [int(jnp.argmax(logits[0, -1]))]
     pos = len(prompt)
     while len(out) < max_new:
-        logits, cache = decode(params, cache,
-                               jnp.asarray([[out[-1]]], jnp.int32),
-                               jnp.int32(pos))
+        logits, cache, _ = decode(params, cache,
+                                  jnp.asarray([[out[-1]]], jnp.int32),
+                                  jnp.int32(pos))
         out.append(int(jnp.argmax(logits[0, 0])))
         pos += 1
     return out

@@ -77,15 +77,15 @@ def lockstep_bucket(cfg, params, prompt, max_new, budget,
     Lb = reg.len_bucket(L)
     toks = np.zeros((1, Lb), np.int32)
     toks[0, :L] = prompt
-    logits, cache = reg.prefill(Lb)(params, jnp.asarray(toks),
-                                    jnp.int32(L))
+    logits, cache, _ = reg.prefill(Lb)(params, jnp.asarray(toks),
+                                       jnp.int32(L))
     cache = align_prefill_cache_dyn(cfg, cache, L, budget)
     out = [int(jnp.argmax(logits[0, -1]))]
     pos = L
     while len(out) < max_new:
-        logits, cache = decode(params, cache,
-                               jnp.asarray([[out[-1]]], jnp.int32),
-                               jnp.int32(pos))
+        logits, cache, _ = decode(params, cache,
+                                  jnp.asarray([[out[-1]]], jnp.int32),
+                                  jnp.int32(pos))
         out.append(int(jnp.argmax(logits[0, 0])))
         pos += 1
     return out
@@ -133,8 +133,8 @@ def test_bucket_prefill_align_matches_exact(cfg):
         Lb = reg.len_bucket(L)
         padded = np.zeros((1, Lb), np.int32)
         padded[:, :L] = prompt
-        lg_b, c_b = reg.prefill(Lb)(params, jnp.asarray(padded),
-                                    jnp.int32(L))
+        lg_b, c_b, _ = reg.prefill(Lb)(params, jnp.asarray(padded),
+                                       jnp.int32(L))
         ring_b = align_prefill_cache_dyn(cfg, c_b, L, budget)
 
         assert np.array_equal(np.asarray(lg_e[0, -1]),
